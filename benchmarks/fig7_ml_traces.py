@@ -74,9 +74,9 @@ def _compiled_trace(dm: DeviceMap):
     sh = NamedSharding(mesh, P("d", None))
     x = jax.ShapeDtypeStruct((len(jax.devices()) * 4, n), jnp.float32, sharding=sh)
     w = jax.ShapeDtypeStruct((n, n), jnp.float32)
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(stepfn, mesh=mesh, in_specs=(P("d", None), P(None, None)),
-                   out_specs=(P("d", None), P(None, None)))
+    fn = jax.shard_map(stepfn, mesh=mesh,
+                       in_specs=(P("d", None), P(None, None)),
+                       out_specs=(P("d", None), P(None, None)))
     hlo = jax.jit(fn).lower(x, w).compile().as_text()
     tr = trace_from_hlo(hlo, dm, name="compiled:psum-step")
     return _autoscale(tr) if tr.n_phases else None

@@ -122,7 +122,7 @@ def _mc_trace_lossy(rec: dict) -> bool:
     return ok
 
 
-def main() -> None:
+def main(json_path: str = JSON_PATH) -> None:
     points, meta = [], []
     for budget in BUDGETS_DB:
         for pol in POLICIES:
@@ -246,10 +246,10 @@ def main() -> None:
 
     # ---- broadcast ARQ over the living channel (ISSUE 6)
     mc_ok = _mc_trace_lossy(rec)
-    with open(JSON_PATH, "w") as f:
+    with open(json_path, "w") as f:
         json.dump({k: round(v, 4) if isinstance(v, float) else v
                    for k, v in rec.items()}, f, indent=1, sort_keys=True)
-    emit(f"fig9,json,{JSON_PATH}")
+    emit(f"fig9,json,{json_path}")
     if not adapt_ok:
         raise SystemExit(
             "fig9: adaptive air efficiency fell below a fixed-rate policy")

@@ -2,9 +2,12 @@
 
 Usage:  PYTHONPATH=src python -m benchmarks.run [--profile] [fig2 ... | all]
 
-Each suite ends with a one-line ``bench.summary`` row — wall-clock and
-simulated points per second (from ``sweep.POINTS_RUN``) — so perf
-regressions are visible directly in CI logs.
+The first row, ``bench.device``, names the backend (platform, device
+kind, count), so a CPU run is visibly a CPU run.  Each suite ends with a
+one-line ``bench.summary`` row — wall-clock and simulated points per
+second (from ``sweep.POINTS_RUN``) — so perf regressions are visible
+directly in CI logs.  JAX's compilation cache is kept where
+``benchmarks.common.use_compile_cache`` says.
 
 ``--profile`` wraps the FIRST selected suite in a ``jax.profiler`` trace
 and writes it to ``profile_trace/`` (open with TensorBoard or Perfetto)
@@ -24,7 +27,9 @@ def main() -> None:
                             fig4_cc_traffic, fig5_mc_traffic, fig6_apps,
                             fig7_ml_traces, fig8_memory,
                             fig9_lossy_channel, simspeed)
+    from benchmarks.common import device_row, use_compile_cache
     from repro.core import sweep
+    use_compile_cache()
     suites = {
         "fig2": fig2_uniform.main,
         "fig3": fig3_latency.main,
@@ -50,6 +55,7 @@ def main() -> None:
     picked = list(dict.fromkeys(suites)) if args == ["all"] else args
     if args == ["all"]:
         picked.remove("fig9_lossy_channel")     # alias of fig9
+    print(device_row(), flush=True)
     for i, name in enumerate(picked):
         t0 = time.perf_counter()
         p0 = sweep.POINTS_RUN

@@ -293,14 +293,13 @@ class SimStatic(NamedTuple):
     # re-selection) — the static ``living`` flag compiles the window
     # updates, and the dynamic carry tables replace wl_serv/wl_perq.
     wl_rate0: jnp.ndarray    # [WMAX, WMAX] i32 host-selected rate entry
-    wl_snr: jnp.ndarray      # [WMAX, WMAX] f32 undrifted SNR map (dB)
+    wl_snr_q: jnp.ndarray    # [WMAX, WMAX] i32 undrifted SNR, 1/SNR_Q dB
     wl_serv_r: jnp.ndarray   # [R] i32 flit cycles per rate entry
     wl_perq_r: jnp.ndarray   # [R, WMAX, WMAX] i32 PER threshold per entry
     wl_gp_q: jnp.ndarray     # [R, WMAX, WMAX] i32 quantized goodput
-    wl_gain_r: jnp.ndarray   # [R] f32 processing gain per entry
-    wl_gbps_r: jnp.ndarray   # [R] f32 line rate per entry
-    wl_pkt_bits: jnp.ndarray  # f32 packet bits (PER recompute under drift)
-    wl_drift_amp: jnp.ndarray   # f32 aging amplitude in dB (0 = static)
+    wl_perq_lut: jnp.ndarray  # [R, L] i32 PER threshold on the SNR grid
+    wl_gp_lut: jnp.ndarray   # [R, L] i32 quantized goodput on the SNR grid
+    wl_drift_amp_q: jnp.ndarray  # i32 aging amplitude, 1/SNR_Q dB (0 = static)
     wl_drift_period: jnp.ndarray  # i32 windows between drift knots
 
 
@@ -1447,7 +1446,7 @@ def pack(topo: Topology, rt: RoutingTables, tt: TrafficTable,
     path on fabrics with wireless interfaces; wireline fabrics (and
     ``phy_spec=None``) run the exact ideal-channel program.
     """
-    from repro.phy.rates import pack_link_state
+    from repro.phy.rates import drift_amp_q, pack_link_state
     fl = floors or {}
     Lw = topo.n_links
     n_inj = tt.n_sources
@@ -1699,20 +1698,20 @@ def pack(topo: Topology, rt: RoutingTables, tt: TrafficTable,
         ctrl_flits=jnp.int32(phy.ctrl_packet_flits),
         wl_rate0=jnp.asarray(pli.rate_idx if living
                              else np.zeros((1, 1), np.int32)),
-        wl_snr=jnp.asarray(pli.snr_pad if living
-                           else np.zeros((1, 1), np.float32)),
+        wl_snr_q=jnp.asarray(pli.snr_q if drift_on
+                             else np.zeros((1, 1), np.int32)),
         wl_serv_r=jnp.asarray(pli.serv_r if living
                               else np.ones(1, np.int32)),
         wl_perq_r=jnp.asarray(pli.perq_r if living
                               else np.zeros((1, 1, 1), np.int32)),
         wl_gp_q=jnp.asarray(pli.gp_q if living
                             else np.zeros((1, 1, 1), np.int32)),
-        wl_gain_r=jnp.asarray(pli.gain_r if living
-                              else np.ones(1, np.float32)),
-        wl_gbps_r=jnp.asarray(pli.gbps_r if living
-                              else np.ones(1, np.float32)),
-        wl_pkt_bits=jnp.float32(phy.pkt_flits * phy.flit_bits),
-        wl_drift_amp=jnp.float32(phy_spec.drift_amp_db if phy_on else 0.0),
+        wl_perq_lut=jnp.asarray(pli.perq_lut if drift_on
+                                else np.zeros((1, 1), np.int32)),
+        wl_gp_lut=jnp.asarray(pli.gp_lut if drift_on
+                              else np.zeros((1, 1), np.int32)),
+        wl_drift_amp_q=jnp.int32(drift_amp_q(phy_spec.drift_amp_db)
+                                 if phy_on else 0),
         wl_drift_period=jnp.int32(max(1, phy_spec.drift_period)
                                   if phy_on else 1),
     )

@@ -23,13 +23,8 @@ def make_mesh(shape, axes, devices=None):
     if len(devices) < n:
         raise ValueError(f"need {n} devices, have {len(devices)} "
                          "(did you set XLA_FLAGS before importing jax?)")
-    # axis_types / AxisType only exist on newer jax; Auto is the default
-    # behaviour there, so omitting it on older versions is equivalent.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, devices=devices[:n],
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -15,19 +15,23 @@ window function cannot be formulated twice without inviting drift.
   ``drift_period`` windows per unordered link (the channel is
   reciprocal), drawn from the same counter-based murmur3 hash the ARQ
   CRC uses — no RNG state in the carry — and linearly interpolated
-  between knots.  Values lie in ``[0, 1)``; the sweep knob
-  ``drift_amp_db`` scales them, so drifted SNR is *monotone
-  non-increasing in the aging amplitude* by construction (the property
-  tests pin this).
+  between knots.  Values lie in ``[0, 1)``, held exactly as integers;
+  the sweep knob ``drift_amp_db`` scales them, so drifted SNR is
+  *monotone non-increasing in the aging amplitude* by construction (the
+  property tests pin this).
 - ``window_tables``: per-window PER thresholds, goodput estimates and
   (under ``reselect``) the per-link argmax over the rate table.  On a
   static channel (``drift_amp_db == 0``) it reads the host-packed
   integer tables ``wl_perq_r`` / ``wl_gp_q`` — the *same* integers the
   host selection pass argmaxed over — so in-scan re-selection is a
-  bitwise no-op vs the one-shot program.  Under drift the engines
-  recompute both in f32 on device; the two engines share this code, so
-  they agree bitwise by construction and the differential tests keep
-  pinning the surrounding step dynamics.
+  bitwise no-op vs the one-shot program.  Under drift the drifted SNR
+  is computed in fixed point (``rates.SNR_Q`` steps per dB) and indexes
+  host-built PER / goodput tables (``rates.snr_lut``).  Everything on
+  the device is integer arithmetic and gathers, so every backend — XLA
+  on the CPU or on a TPU, either engine — derives the same integers.
+  Evaluating the PER chain in f32 on the device does not: the last bits
+  of ``power``/``exp``/``log1p`` differ between backends, and one ulp
+  flips a quantized threshold and, through it, CRC outcomes.
 - ``make_window_fn``: closes over the static flags and returns the
   ``window_fn(st, t)`` the step (via ``lax.cond`` on the window
   boundary) and the drain-aware driver (boundary replay after early
@@ -39,7 +43,7 @@ import jax.numpy as jnp
 
 from repro.core.chunked import CHUNK_CYCLES
 from repro.core.constants import WMAX
-from repro.phy.rates import GP_SCALE, PER_Q
+from repro.phy.rates import SNR_LUT_LO, SNR_Q
 from repro.phy.retx import crc_hash
 
 # Domain-separation constant: the drift walk and the CRC draw share the
@@ -48,29 +52,39 @@ DRIFT_SEED = 0xD51F7EED
 
 
 def drift_unit(phy_seed, win, period):
-    """[WMAX, WMAX] f32 aging offsets in ``[0, 1)`` for scan window ``win``.
+    """[WMAX, WMAX] int32 aging offsets for scan window ``win``.
 
-    Symmetric (one walk per unordered link, mirrored — the physical
-    channel is reciprocal) and deterministic in ``(phy_seed, win,
-    period)``.  Knots sit every ``period`` windows; between knots the
+    The offset is ``drift_unit(...) / (period << 24)``, in ``[0, 1)``;
+    ``period <= 127`` keeps it within int32.  Symmetric (one walk per
+    unordered link, mirrored — the physical channel is reciprocal) and
+    deterministic in ``(phy_seed, win, period)``.  Knots sit every
+    ``period`` windows, each the hash's top 24 bits; between knots the
     offset is the exact linear interpolation, so the walk is slow on the
-    scale of a scan window, as thermal cycling is.  The hash's top 24
-    bits become the f32 mantissa — exact, no rounding ties.
+    scale of a scan window, as thermal cycling is.
     """
-    i32, f32 = jnp.int32, jnp.float32
+    i32 = jnp.int32
     ids = jnp.arange(WMAX, dtype=i32)
     lid = (jnp.minimum(ids[:, None], ids[None, :]) * WMAX
            + jnp.maximum(ids[:, None], ids[None, :]))
     dseed = jnp.uint32(phy_seed) ^ jnp.uint32(DRIFT_SEED)
     k = (win // period).astype(i32)
-    frac = (win % period).astype(f32) / f32(period)
 
     def knot(kk):
-        return (crc_hash(dseed, lid, kk) >> jnp.uint32(8)
-                ).astype(f32) * f32(1.0 / (1 << 24))
+        return (crc_hash(dseed, lid, kk) >> jnp.uint32(8)).astype(i32)
 
     h0, h1 = knot(k), knot(k + 1)
-    return h0 + (h1 - h0) * frac
+    return h0 * period + (h1 - h0) * (win % period)
+
+
+def drift_db_q(amp_q, u, period):
+    """``floor(amp_q * u / (period << 24))``, exact in int32.
+
+    The SNR loss on the ``rates.SNR_Q`` grid for an amplitude ``amp_q <
+    2**15`` and a walk value ``u`` of ``drift_unit``: ``u`` is split
+    into 16-bit halves so that no product exceeds 31 bits.
+    """
+    hi, lo = u >> 16, u & 0xFFFF
+    return (amp_q * hi + ((amp_q * lo) >> 16)) // (period << 8)
 
 
 def window_tables(ss, rate_prev, win, drift_on: bool, reselect: bool):
@@ -80,8 +94,8 @@ def window_tables(ss, rate_prev, win, drift_on: bool, reselect: bool):
     shared by construction); ``rate_prev`` is the carry's current
     per-link rate-table entry.  Static python flags pick the program:
 
-    - ``drift_on``: recompute PER thresholds and quantized goodput from
-      the drifted SNR (f32 transcendentals, identical in both engines);
+    - ``drift_on``: look the PER thresholds and quantized goodput of
+      the drifted fixed-point SNR up in the host-built grid tables;
       otherwise read the host-packed integer tables — bitwise the
       integers ``rates.select_rates`` argmaxed over.
     - ``reselect``: per-link argmax over the quantized goodput (first
@@ -90,19 +104,14 @@ def window_tables(ss, rate_prev, win, drift_on: bool, reselect: bool):
       drifts under the *static* selection — the fig9 "adaptive-static"
       arm).
     """
-    i32, f32 = jnp.int32, jnp.float32
+    i32 = jnp.int32
     if drift_on:
         u = drift_unit(ss.phy_seed, win, ss.wl_drift_period)
-        snr = ss.wl_snr - ss.wl_drift_amp * u
-        gamma = jnp.power(f32(10.0), snr[None] / 10.0) \
-            * ss.wl_gain_r[:, None, None]
-        ber = f32(0.5) * jnp.exp(-gamma / 2)
-        per = -jnp.expm1(ss.wl_pkt_bits
-                         * jnp.log1p(-jnp.minimum(ber, f32(0.999999))))
-        perq_r = jnp.minimum(jnp.ceil(per * f32(1 << PER_Q)),
-                             f32((1 << PER_Q) - 1)).astype(i32)
-        gp_q = jnp.rint(ss.wl_gbps_r[:, None, None] * (1 - per)
-                        * f32(GP_SCALE)).astype(i32)
+        snr_q = ss.wl_snr_q - drift_db_q(ss.wl_drift_amp_q, u,
+                                         ss.wl_drift_period)
+        idx = jnp.clip(snr_q - SNR_LUT_LO * SNR_Q, 0,
+                       ss.wl_perq_lut.shape[1] - 1)
+        perq_r, gp_q = ss.wl_perq_lut[:, idx], ss.wl_gp_lut[:, idx]
     else:
         perq_r, gp_q = ss.wl_perq_r, ss.wl_gp_q
     if reselect:

@@ -43,6 +43,12 @@ from repro.phy.channel import (PhySweepSpec, ber_from_snr, link_snr_db,
 
 PER_Q = 16                    # PER quantization: threshold in [0, 2^16]
 GP_SCALE = 1 << 20            # goodput quantization: int steps per 2^-20 Gbps
+# Living-channel SNR grid: drifted SNR is fixed point, SNR_Q steps per dB,
+# and indexes host-built PER / goodput tables covering
+# [SNR_LUT_LO, SNR_LUT_HI) dB.  Both ends must be saturated (PER exactly
+# 1 or 0 at every rate), so clipping an index off the grid is exact.
+SNR_Q = 64
+SNR_LUT_LO, SNR_LUT_HI = -16, 48
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,11 +91,11 @@ class PhyLinkInfo:
     # per-entry tables for the living-channel window updates (phy.living)
     serv_r: np.ndarray      # [R] int32 flit cycles of each table entry
     epb_r: np.ndarray       # [R] float pJ/bit of each table entry
-    gain_r: np.ndarray      # [R] float32 processing gain of each entry
-    gbps_r: np.ndarray      # [R] float32 line rate of each entry
     perq_r: np.ndarray      # [R, WMAX, WMAX] int32 PER threshold per entry
     gp_q: np.ndarray        # [R, WMAX, WMAX] int32 quantized goodput
-    snr_pad: np.ndarray     # [WMAX, WMAX] float32 padded SNR map
+    snr_q: np.ndarray       # [WMAX, WMAX] int32 SNR map, 1 / SNR_Q dB steps
+    perq_lut: np.ndarray    # [R, L] int32 PER threshold on the SNR grid
+    gp_lut: np.ndarray      # [R, L] int32 quantized goodput on the SNR grid
 
 
 def rate_per_matrix(snr_db: np.ndarray, packet_bits: int,
@@ -111,6 +117,15 @@ def expected_goodput(per_r: np.ndarray, table=DEFAULT_RATE_TABLE
     return rates[:, None, None] * (1.0 - per_r)
 
 
+def per_q(per_r: np.ndarray) -> np.ndarray:
+    """PER quantized onto the 16-bit CRC-hash range (int32).
+
+    Ceil, so a nonzero PER never rounds to "lossless".
+    """
+    return np.minimum(np.ceil(per_r * float(1 << PER_Q)),
+                      float((1 << PER_Q) - 1)).astype(np.int32)
+
+
 def goodput_q(per_r: np.ndarray, table=DEFAULT_RATE_TABLE) -> np.ndarray:
     """[R, W, W] int32 expected goodput in ``1 / GP_SCALE`` Gbps steps.
 
@@ -121,6 +136,32 @@ def goodput_q(per_r: np.ndarray, table=DEFAULT_RATE_TABLE) -> np.ndarray:
     """
     return np.rint(expected_goodput(per_r, table) * GP_SCALE
                    ).astype(np.int32)
+
+
+def snr_lut(packet_bits: int, table=DEFAULT_RATE_TABLE):
+    """``(perq_lut, gp_lut)``: [R, L] int32 tables on the SNR grid.
+
+    Entry ``i`` holds the quantized PER and goodput of each rate at
+    ``SNR_LUT_LO + i / SNR_Q`` dB.  The living channel gathers from them
+    instead of evaluating ``power``/``exp``/``log1p`` on the device,
+    whose last bits differ between backends and would flip quantized
+    thresholds.
+    """
+    snr = SNR_LUT_LO + np.arange((SNR_LUT_HI - SNR_LUT_LO) * SNR_Q) / SNR_Q
+    per_r = rate_per_matrix(snr, packet_bits, table)          # [R, L]
+    perq, gp = per_q(per_r), goodput_q(per_r[:, :, None], table)[:, :, 0]
+    if (perq[:, 0] != (1 << PER_Q) - 1).any() or (gp[:, 0] != 0).any() \
+            or (perq[:, -1] != 0).any() \
+            or (gp[:, -1] != goodput_q(np.zeros((len(table), 1, 1)),
+                                       table)[:, 0, 0]).any():
+        raise ValueError("the SNR grid does not saturate the PER of every "
+                         "rate at both ends; widen SNR_LUT_LO/SNR_LUT_HI")
+    return perq, gp
+
+
+def drift_amp_q(amp_db: float) -> int:
+    """Aging amplitude on the SNR grid (``1 / SNR_Q`` dB steps)."""
+    return int(round(amp_db * SNR_Q))
 
 
 def select_rates(per_r: np.ndarray, table=DEFAULT_RATE_TABLE) -> np.ndarray:
@@ -223,31 +264,35 @@ def link_tables(topo: Topology, phy: PhyParams,
     epb = np.zeros((WMAX, WMAX), np.float64)
     perq_r = np.zeros((R, WMAX, WMAX), np.int32)
     gp_q = np.zeros((R, WMAX, WMAX), np.int32)
-    snr_pad = np.zeros((WMAX, WMAX), np.float32)
+    snr_q = np.zeros((WMAX, WMAX), np.int32)
     ii, jj = np.meshgrid(np.arange(n_wi), np.arange(n_wi), indexing="ij")
     per_sel = per_r[idx, ii, jj]
     rate_idx[:n_wi, :n_wi] = idx
     serv_r = phy.wireless_flit_cycles * np.asarray(
         [e.serv_scale for e in table], np.int32)
     serv[:n_wi, :n_wi] = serv_r[idx]
-    # quantize PER onto the 16-bit CRC-hash range; ceil so a nonzero PER
-    # never rounds to "lossless"
-    perq_r[:, :n_wi, :n_wi] = np.minimum(
-        np.ceil(per_r * float(1 << PER_Q)), float((1 << PER_Q) - 1)
-    ).astype(np.int32)
+    perq_r[:, :n_wi, :n_wi] = per_q(per_r)
     perq[:n_wi, :n_wi] = perq_r[idx, ii, jj]
     per[:n_wi, :n_wi] = per_sel
     epb_r = phy.e_wireless_pj_bit * np.asarray(
         [e.epb_scale for e in table])
     epb[:n_wi, :n_wi] = epb_r[idx]
     gp_q[:, :n_wi, :n_wi] = goodput_q(per_r, table)
-    snr_pad[:n_wi, :n_wi] = snr
+    snr_q[:n_wi, :n_wi] = np.rint(snr * SNR_Q)
+    if spec.drift_amp_db > 0:
+        # the exact integer drift (phy.living.drift_db_q) fits int32 only
+        # within these bounds
+        if not 1 <= spec.drift_period <= 127:
+            raise ValueError("drift_period must lie in [1, 127]")
+        if not 0 <= drift_amp_q(spec.drift_amp_db) < 1 << 15:
+            raise ValueError(f"drift_amp_db must lie in [0, "
+                             f"{(1 << 15) / SNR_Q}) dB")
+        perq_lut, gp_lut = snr_lut(packet_bits, table)
+    else:
+        perq_lut = gp_lut = np.zeros((R, 1), np.int32)
     return PhyLinkInfo(spec=spec, table=tuple(table), n_wi=n_wi,
                        rate_idx=rate_idx, serv=serv, perq=perq, per=per,
                        epb=epb, snr_db=snr,
                        serv_r=serv_r, epb_r=epb_r,
-                       gain_r=np.asarray([e.gain for e in table],
-                                         np.float32),
-                       gbps_r=np.asarray([e.gbps for e in table],
-                                         np.float32),
-                       perq_r=perq_r, gp_q=gp_q, snr_pad=snr_pad)
+                       perq_r=perq_r, gp_q=gp_q, snr_q=snr_q,
+                       perq_lut=perq_lut, gp_lut=gp_lut)

@@ -65,7 +65,6 @@ def make_dp_train_step(model, opt, mesh, cc: CompressionConfig):
     int8+error-feedback wire format.  Returns
     train_step(params, opt_state, err, batch) -> (params, opt, err, metrics).
     """
-    from jax.experimental.shard_map import shard_map
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
     def local_step(params, opt_state, err, batch):
@@ -92,7 +91,7 @@ def make_dp_train_step(model, opt, mesh, cc: CompressionConfig):
 
     def wrapped(params, opt_state, err, batch):
         b_spec = jax.tree.map(lambda _: P(dp), batch)
-        fn = shard_map(
+        fn = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(specs_like(params, P()),
                       jax.tree.map(lambda _: P(), opt_state,
@@ -103,7 +102,7 @@ def make_dp_train_step(model, opt, mesh, cc: CompressionConfig):
                                     is_leaf=lambda x: hasattr(x, "shape")),
                        specs_like(err, P()),
                        {"loss": P(), "gnorm": P(), "lr": P()}),
-            check_rep=False)
+            check_vma=False)
         return fn(params, opt_state, err, batch)
 
     return jax.jit(wrapped)

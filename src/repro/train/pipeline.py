@@ -95,19 +95,11 @@ def make_pp_loss(cfg: ModelConfig, mesh, *, n_stages: int, n_micro: int,
         mask = jnp.where(stage == S - 1, jnp.float32(1), jnp.float32(0))
         return jax.lax.psum(out * mask, axis)
 
-    if hasattr(jax, "shard_map"):
-        pp = jax.shard_map(
-            pipeline, mesh=mesh, axis_names={axis},
-            in_specs=(P(axis), P(), P()),
-            out_specs=P(),
-            check_vma=False)
-    else:   # older jax: experimental API, check_rep instead of check_vma
-        from jax.experimental.shard_map import shard_map as _shard_map
-        pp = _shard_map(
-            pipeline, mesh=mesh,
-            in_specs=(P(axis), P(), P()),
-            out_specs=P(),
-            check_rep=False)
+    pp = jax.shard_map(
+        pipeline, mesh=mesh, axis_names={axis},
+        in_specs=(P(axis), P(), P()),
+        out_specs=P(),
+        check_vma=False)
 
     def loss_fn(params, batch):
         emb = params["embed"]

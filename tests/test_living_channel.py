@@ -38,6 +38,8 @@ from repro.core.routing import compute_routing
 from repro.core.sweep import SweepPoint, run_sweep_batched
 from repro.core.topology import build_xcym
 from repro.phy import PhySweepSpec, crc_fail, drift_unit, window_tables
+from repro.phy.rates import (SNR_Q, drift_amp_q, link_tables, per_q,
+                             rate_per_matrix)
 from repro.workloads.trace import Trace, mcast, p2p, phase
 
 _TRACE = Trace("living", 8, [
@@ -93,17 +95,61 @@ def _living_static(drift_amp=4.0, seed=2):
 
 
 def test_drift_unit_is_a_symmetric_unit_walk():
-    u0 = np.asarray(drift_unit(2, jnp.int32(0), jnp.int32(8)))
-    u5 = np.asarray(drift_unit(2, jnp.int32(5), jnp.int32(8)))
+    def unit(win):        # the walk as a fraction of its full scale
+        u = np.asarray(drift_unit(2, jnp.int32(win), jnp.int32(8)))
+        return u.astype(np.int64) / (8 << 24)
+
+    u0, u5 = unit(0), unit(5)
     for u in (u0, u5):
         assert ((u >= 0.0) & (u < 1.0)).all()
         assert np.array_equal(u, u.T)            # reciprocal channel
     assert not np.array_equal(u0, u5)            # the channel moves
     # between knots the walk is the exact lerp of its endpoints
-    k0 = np.asarray(drift_unit(2, jnp.int32(8), jnp.int32(8)))
-    k1 = np.asarray(drift_unit(2, jnp.int32(16), jnp.int32(8)))
-    mid = np.asarray(drift_unit(2, jnp.int32(12), jnp.int32(8)))
-    np.testing.assert_allclose(mid, k0 + (k1 - k0) * 0.5, atol=1e-6)
+    k0, k1, mid = unit(8), unit(16), unit(12)
+    assert np.array_equal(mid, k0 + (k1 - k0) * 0.5)
+
+
+def test_drift_db_q_is_the_exact_scaled_floor():
+    """The split int32 product equals floor(amp * u / (period << 24))."""
+    from repro.phy.living import drift_db_q
+    rng = np.random.default_rng(0)
+    for period in (1, 8, 127):
+        u = rng.integers(0, period << 24, 4096)
+        amp = rng.integers(0, 1 << 15, 4096)
+        got = np.asarray(drift_db_q(jnp.asarray(amp, jnp.int32),
+                                    jnp.asarray(u, jnp.int32),
+                                    jnp.int32(period)))
+        want = [a * x // (period << 24) for a, x in zip(amp.tolist(),
+                                                        u.tolist())]
+        assert got.tolist() == want
+
+
+@pytest.mark.parametrize("win", [0, 5, 13])
+def test_drifted_perq_matches_host_per_chain(win):
+    """The device path (integer walk, fixed-point SNR, table gather)
+    equals the host PER chain evaluated at the same fixed-point SNR."""
+    ss = _living_static()
+    _, _, perq = window_tables(ss, ss.wl_rate0, jnp.int32(win), True, False)
+    period = int(ss.wl_drift_period)
+    u = np.asarray(drift_unit(ss.phy_seed, jnp.int32(win),
+                              jnp.int32(period))).astype(np.int64)
+    snr_q = (np.asarray(ss.wl_snr_q)
+             - int(ss.wl_drift_amp_q) * u // (period << 24))
+    per_r = rate_per_matrix(snr_q / SNR_Q,
+                            DEFAULT_PHY.pkt_flits * DEFAULT_PHY.flit_bits)
+    want = np.take_along_axis(per_q(per_r), np.asarray(ss.wl_rate0)[None],
+                              axis=0)[0]
+    n = int(ss.n_wi)
+    off = ~np.eye(n, dtype=bool)
+    assert np.array_equal(np.asarray(perq)[:n, :n][off], want[:n, :n][off])
+
+
+@pytest.mark.parametrize("spec", [
+    PhySweepSpec(drift_amp_db=4.0, drift_period=128),
+    PhySweepSpec(drift_amp_db=512.0)], ids=["period", "amplitude"])
+def test_drift_knobs_beyond_int32_range_are_rejected(spec):
+    with pytest.raises(ValueError):
+        link_tables(build_xcym(4, 4, Fabric.WIRELESS), DEFAULT_PHY, spec)
 
 
 def test_drifted_link_quality_monotone_in_amplitude_grid():
@@ -111,7 +157,7 @@ def test_drifted_link_quality_monotone_in_amplitude_grid():
     ss = _living_static()
     prev = None
     for amp in (0.0, 2.0, 4.0, 8.0):
-        sa = ss._replace(wl_drift_amp=jnp.float32(amp))
+        sa = ss._replace(wl_drift_amp_q=jnp.int32(drift_amp_q(amp)))
         _, _, perq = window_tables(sa, ss.wl_rate0, jnp.int32(3),
                                    True, False)
         perq = np.asarray(perq)
@@ -128,7 +174,7 @@ if HAVE_HYP:
         lo, hi = sorted((a1, a2))
         out = []
         for amp in (lo, hi):
-            sa = ss._replace(wl_drift_amp=jnp.float32(amp))
+            sa = ss._replace(wl_drift_amp_q=jnp.int32(drift_amp_q(amp)))
             _, _, perq = window_tables(sa, ss.wl_rate0, jnp.int32(win),
                                        True, False)
             out.append(np.asarray(perq))
