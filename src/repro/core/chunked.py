@@ -47,6 +47,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import scope
 from repro.core.traffic import NO_PKT
 
 # Cycles per inner scan chunk.  Small enough that a drained lane stops
@@ -133,8 +134,9 @@ def run_chunked(step, ss, st, mem_on: bool, chunk: int = CHUNK_CYCLES,
         # per-cycle freeze: a lane whose budget ends mid-chunk stops
         # accumulating exactly at its budget (lax.cond, not where: under
         # lax.map the predicate is a plain scalar, so XLA skips the body)
-        return jax.lax.cond(t < cycles, lambda x: step(ss, x, t),
-                            lambda x: x, s), None
+        with scope("driver.cycle"):
+            return jax.lax.cond(t < cycles, lambda x: step(ss, x, t),
+                                lambda x: x, s), None
 
     def body(carry):
         s, t0 = carry
@@ -143,17 +145,19 @@ def run_chunked(step, ss, st, mem_on: bool, chunk: int = CHUNK_CYCLES,
 
     def cond(carry):
         s, t0 = carry
-        return (t0 < cycles) & ~drain_done(ss, s, t0, mem_on)
+        with scope("driver.drain_check"):
+            return (t0 < cycles) & ~drain_done(ss, s, t0, mem_on)
 
     st, t0 = jax.lax.while_loop(cond, body, (st, i32(0)))
-    if window_fn is not None:
-        # first window boundary the in-step cond did NOT fire: cycles in
-        # [0, t0) all executed, so that is the first multiple of the
-        # window cadence >= t0
-        W = i32(CHUNK_CYCLES)
-        tb = ((t0 + W - 1) // W) * W
-        st, _ = jax.lax.while_loop(
-            lambda c: c[1] < cycles,
-            lambda c: (window_fn(c[0], c[1]), c[1] + W),
-            (st, tb))
-    return _finalize(ss, st, t0)
+    with scope("driver.finalize"):
+        if window_fn is not None:
+            # first window boundary the in-step cond did NOT fire: cycles
+            # in [0, t0) all executed, so that is the first multiple of
+            # the window cadence >= t0
+            W = i32(CHUNK_CYCLES)
+            tb = ((t0 + W - 1) // W) * W
+            st, _ = jax.lax.while_loop(
+                lambda c: c[1] < cycles,
+                lambda c: (window_fn(c[0], c[1]), c[1] + W),
+                (st, tb))
+        return _finalize(ss, st, t0)

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.constants import PhyParams, SimParams
 from repro.core.simulator import PackedSim, SimState
 
@@ -166,20 +167,41 @@ def compute_metrics_batch(pss: Sequence[PackedSim], st: SimState,
                           names: Sequence[str],
                           offered_loads: Sequence[float],
                           cycles: int | None = None) -> list[Metrics]:
-    """Extract §IV metrics for a batched ``SimState`` (leading batch axis)."""
-    f32 = np.float32
-    el, es, ec, er = _energy_terms(
-        jnp.stack([ps.ss.b_epb for ps in pss]),
-        st.counts_into, st.count_switch, st.ctrl_count,
-        st.awake_cycles, st.sleep_cycles,
-        jnp.asarray([f32(ps.phy.flit_bits) for ps in pss]),
-        jnp.asarray([f32(ps.phy.e_switch_pj_bit) for ps in pss]),
-        jnp.asarray([f32(ps.phy.ctrl_packet_flits * ps.phy.flit_bits
-                         * ps.phy.e_wireless_pj_bit) for ps in pss]),
-        jnp.asarray([f32(ps.phy.rx_idle_pj_cycle) for ps in pss]),
-        jnp.asarray([f32(ps.phy.rx_sleep_pj_cycle) for ps in pss]))
-    el, es, ec, er = (np.asarray(x) for x in (el, es, ec, er))
+    """Extract §IV metrics for a batched ``SimState`` (leading batch axis).
 
+    Host spans (``spans``): ``compute_metrics_batch`` around
+    ``metrics.energy`` (the jitted energy terms, read to the host) and
+    ``metrics.lanes`` (the per-lane reads and float math), with counters
+    ``budget_lane_cycles`` (Σ ``cycles_run``) and ``executed_lane_cycles``
+    (Σ ``drain_cycle``).
+    """
+    with spans.span("compute_metrics_batch") as counters:
+        with spans.span("metrics.energy"):
+            f32 = np.float32
+            terms = _energy_terms(
+                jnp.stack([ps.ss.b_epb for ps in pss]),
+                st.counts_into, st.count_switch, st.ctrl_count,
+                st.awake_cycles, st.sleep_cycles,
+                jnp.asarray([f32(ps.phy.flit_bits) for ps in pss]),
+                jnp.asarray([f32(ps.phy.e_switch_pj_bit) for ps in pss]),
+                jnp.asarray([f32(ps.phy.ctrl_packet_flits * ps.phy.flit_bits
+                                 * ps.phy.e_wireless_pj_bit) for ps in pss]),
+                jnp.asarray([f32(ps.phy.rx_idle_pj_cycle) for ps in pss]),
+                jnp.asarray([f32(ps.phy.rx_sleep_pj_cycle) for ps in pss]))
+            terms = [np.asarray(x) for x in terms]
+        with spans.span("metrics.lanes"):
+            out = _lane_metrics(pss, st, terms, names, offered_loads,
+                                cycles)
+        counters["budget_lane_cycles"] = sum(m.cycles_run for m in out)
+        counters["executed_lane_cycles"] = sum(m.drain_cycle for m in out)
+    return out
+
+
+def _lane_metrics(pss: Sequence[PackedSim], st: SimState, terms: list,
+                  names: Sequence[str], offered_loads: Sequence[float],
+                  cycles: int | None) -> list[Metrics]:
+    """Per-lane host reads of the batched state: one ``Metrics`` each."""
+    el, es, ec, er = terms
     out = []
     for g, ps in enumerate(pss):
         phy: PhyParams = ps.phy

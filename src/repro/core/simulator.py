@@ -179,7 +179,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import chunked
+from repro.core import chunked, spans
 from repro.core.chunked import CHUNK_CYCLES
 from repro.core.constants import (WMAX, LinkClass, MacMode, PhyParams,
                                   SimParams)
@@ -494,11 +494,15 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
     classA = (jnp.arange(V) < V // 2)                        # [V]
     b_ids = jnp.arange(B, dtype=jnp.int32)
 
-    def step(ss: SimStatic, st: SimState, t: jnp.ndarray) -> SimState:
+    @spans.staged
+    def step(ss: SimStatic, st: SimState, t: jnp.ndarray,
+             stage) -> SimState:
+        stage("step.arrive")
         i32 = jnp.int32
         t = t.astype(i32)
         post = (t >= ss.warmup).astype(i32)
         if living:
+            stage("step.window")
             # living channel: refresh the dynamic per-pair link tables at
             # every scan-window boundary (cadence = CHUNK_CYCLES, a fixed
             # semantic constant — not the driver's execution chunk).  The
@@ -508,6 +512,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             wfn = make_window_fn(ss, drift_on, reselect)
             st = jax.lax.cond(t % i32(CHUNK_CYCLES) == 0,
                               lambda s: wfn(s, t), lambda s: s, st)
+            stage("step.arrive")
         rot = t % NC
         S = ss.next_out.shape[0]
         M = ss.mc_member.shape[0]
@@ -538,6 +543,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         occ = jnp.where(active, rcvd - st.sent, 0)
 
         # ---- 2a. output-VC claims ---------------------------------------
+        stage("step.vc_claim")
         # one new downstream-VC allocation per target buffer per cycle.
         # VC classes break wormhole cycles (see module docstring): packets
         # before their wireless hop claim VCs [0, V/2), after it [V/2, V);
@@ -674,6 +680,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             plen_bv = ss.pkt_len
 
         # ---- 2b. forwarding: wired links, ejection, wireless -------------
+        stage("step.forward")
         inflight = pipe.sum(axis=2)                              # [B, V]
         ob_c = jnp.clip(out_buf, 0, B - 1)
         ovc_c = jnp.clip(out_vc, 0, V - 1)
@@ -856,6 +863,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
 
         # ---- phase barrier bookkeeping (trace tables; raw counts — the
         # dependency structure must not depend on the stats warm-up)
+        stage("step.phase")
         phv = ss.phases[psrc_c, pidx_c]                          # [B, V]
         phase_del = st.phase_del \
             + (tail_ej & (phv == st.cur_phase)).sum().astype(i32)
@@ -880,6 +888,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         phase_del = jnp.where(complete, 0, phase_del)
 
         # ---- closed-loop memory: bank model + reply gating (mem tables)
+        stage("step.memory")
         rdy, outst, dead = st.rdy, st.outst, st.dead
         bank_busy, bank_row = st.bank_busy, st.bank_row
         amat_sum, amat_pkts = st.amat_sum, st.amat_pkts
@@ -961,6 +970,8 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             dec = (rep_ns & (req_ns == Narr[None, :])).sum(0).astype(i32)
             outst = outst - dec
 
+        # ---- 2b, continued: delivery downstream --------------------------
+        stage("step.forward")
         # non-eject: deliver downstream via the src_of inverse map — each
         # target (buffer, vc) gathers from the unique upstream slot feeding
         # it (identity-checked against out_buf/out_vc to survive slot reuse)
@@ -1115,6 +1126,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         out_is_ej = jnp.where(freed, False, out_is_ej)
 
         # ---- 3. injection -------------------------------------------------
+        stage("step.inject")
         N, K = ss.births.shape
         n_ar = jnp.arange(N, dtype=i32)
         qh = jnp.clip(st.q_head, 0, K - 1)
@@ -1201,6 +1213,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         inj_vc = jnp.where(done, -1, inj_vc)
 
         # ---- 4. receiver wake/sleep accounting ([17]) ---------------------
+        stage("step.rx_sleep")
         rx_ids = ss.rx0 + jnp.arange(WMAX, dtype=i32)
         rx_got = jnp.take(arrive.sum(axis=1), jnp.clip(rx_ids, 0, B - 1)) > 0
         rx_busy = jnp.take(busy_until, jnp.clip(rx_ids, 0, B - 1)) > t
@@ -1273,6 +1286,13 @@ def _chunk_point(ss: SimStatic, st: SimState, B: int, mem_on: bool,
     return chunked.run_chunked(
         make_step(B, mem_on, phy_on, drift_on, reselect), ss, st,
         mem_on, chunk, window_fn=wfn)
+
+
+@jax.jit
+def _batch_of_one(st: SimState) -> SimState:
+    """A single lane's state with a batch axis of one, in one dispatch
+    (an eager reshape per leaf costs a dispatch each)."""
+    return jax.tree_util.tree_map(lambda x: x[None], st)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7),
@@ -1775,6 +1795,12 @@ def run_batch(pss: Sequence[PackedSim], cycles: int | None = None,
     ``driver="monolithic"`` selects the fixed-length single-scan driver
     (all lanes must then share one budget) — the differential oracle the
     chunked default is pinned against.
+
+    Host spans (``spans``): ``run_batch`` around ``run_batch.init``
+    (tables and initial state), ``run_batch.dispatch`` (the jitted driver
+    call, any compile included; again after the wait where a batch of
+    one gets its batch axis or the shards are joined) and
+    ``run_batch.wait`` (``block_until_ready`` on the driver's output).
     """
     if not pss:
         raise ValueError("run_batch needs at least one point")
@@ -1801,45 +1827,62 @@ def run_batch(pss: Sequence[PackedSim], cycles: int | None = None,
     living = drift_on or reselect
     Rr = int(pss[0].ss.wl_serv_r.shape[0])
     G = len(pss)
-    if G == 1:
-        st = init_state(*sdims, mem_on=mem_on, phy_on=phy_on,
-                        living=living, R=Rr)
-        out = _run_one_mono(pss[0].ss, st, mono_cycles, B, mem_on,
-                            phy_on, drift_on, reselect) if mono else \
-            _run_one(_budgeted(pss[0], cycles), st, B, mem_on, phy_on,
-                     chunk, drift_on, reselect)
-        out = jax.tree_util.tree_map(lambda x: x[None], out)
-        return jax.block_until_ready(out)
-    ss = _tree_stack([_budgeted(ps, cycles) for ps in pss])
-    st = init_state_batch(G, *sdims, mem_on=mem_on, phy_on=phy_on,
-                          living=living, R=Rr)
-    D = devices if devices is not None else jax.local_device_count()
-    D = min(D, G)
-    if D > 1:
-        Gp = int(np.ceil(G / D) * D)
-        if Gp != G:
-            pad = jax.tree_util.tree_map(
-                lambda x: jnp.repeat(x[-1:], Gp - G, axis=0), ss)
-            ss = jax.tree_util.tree_map(
-                lambda a, b: jnp.concatenate([a, b]), ss, pad)
-            st = init_state_batch(Gp, *sdims, mem_on=mem_on, phy_on=phy_on,
+    with spans.span("run_batch"):
+        if G == 1:
+            with spans.span("run_batch.init"):
+                ss = pss[0].ss if mono else _budgeted(pss[0], cycles)
+                st = init_state(*sdims, mem_on=mem_on, phy_on=phy_on,
+                                living=living, R=Rr)
+            with spans.span("run_batch.dispatch"):
+                out = _run_one_mono(ss, st, mono_cycles, B, mem_on,
+                                    phy_on, drift_on, reselect) if mono \
+                    else _run_one(ss, st, B, mem_on, phy_on, chunk,
+                                  drift_on, reselect)
+            with spans.span("run_batch.wait"):
+                jax.block_until_ready(out)
+            # the batch axis goes on after the wait: an op on the driver's
+            # output would itself hold the host while the device runs
+            with spans.span("run_batch.dispatch"):
+                return jax.block_until_ready(_batch_of_one(out))
+        D = devices if devices is not None else jax.local_device_count()
+        D = min(D, G)
+        with spans.span("run_batch.init"):
+            ss = _tree_stack([_budgeted(ps, cycles) for ps in pss])
+            st = init_state_batch(G, *sdims, mem_on=mem_on, phy_on=phy_on,
                                   living=living, R=Rr)
-        shard = jax.tree_util.tree_map(
-            lambda x: x.reshape((D, Gp // D) + x.shape[1:]), ss)
-        st_sh = jax.tree_util.tree_map(
-            lambda x: x.reshape((D, Gp // D) + x.shape[1:]), st)
-        out = _run_pmapped_mono(shard, st_sh, mono_cycles, B, mem_on,
-                                phy_on, drift_on, reselect) if mono else \
-            _run_pmapped(shard, st_sh, B, mem_on, phy_on, chunk,
-                         drift_on, reselect)
-        out = jax.tree_util.tree_map(
-            lambda x: x.reshape((Gp,) + x.shape[2:])[:G], out)
-    else:
-        out = _run_mapped_mono(ss, st, mono_cycles, B, mem_on, phy_on,
-                               drift_on, reselect) \
-            if mono else _run_mapped(ss, st, B, mem_on, phy_on, chunk,
-                                     drift_on, reselect)
-    return jax.block_until_ready(out)
+            if D > 1:
+                Gp = int(np.ceil(G / D) * D)
+                if Gp != G:
+                    pad = jax.tree_util.tree_map(
+                        lambda x: jnp.repeat(x[-1:], Gp - G, axis=0), ss)
+                    ss = jax.tree_util.tree_map(
+                        lambda a, b: jnp.concatenate([a, b]), ss, pad)
+                    st = init_state_batch(Gp, *sdims, mem_on=mem_on,
+                                          phy_on=phy_on, living=living, R=Rr)
+                ss = jax.tree_util.tree_map(
+                    lambda x: x.reshape((D, Gp // D) + x.shape[1:]), ss)
+                st = jax.tree_util.tree_map(
+                    lambda x: x.reshape((D, Gp // D) + x.shape[1:]), st)
+        with spans.span("run_batch.dispatch"):
+            if D > 1:
+                out = _run_pmapped_mono(ss, st, mono_cycles, B, mem_on,
+                                        phy_on, drift_on, reselect) \
+                    if mono else _run_pmapped(ss, st, B, mem_on, phy_on,
+                                              chunk, drift_on, reselect)
+            else:
+                out = _run_mapped_mono(ss, st, mono_cycles, B, mem_on,
+                                       phy_on, drift_on, reselect) \
+                    if mono else _run_mapped(ss, st, B, mem_on, phy_on,
+                                             chunk, drift_on, reselect)
+        with spans.span("run_batch.wait"):
+            jax.block_until_ready(out)
+        if D == 1:
+            return out
+        # the shards are joined and the padding cut after the wait, as
+        # the batch of one's axis is
+        with spans.span("run_batch.dispatch"):
+            return jax.block_until_ready(jax.tree_util.tree_map(
+                lambda x: x.reshape((Gp,) + x.shape[2:])[:G], out))
 
 
 def run(ps: PackedSim, cycles: int | None = None, driver: str = "chunked",

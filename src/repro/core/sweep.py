@@ -34,17 +34,13 @@ import dataclasses
 import functools
 from typing import Iterable, Sequence
 
-from repro.core import simulator, traffic
+from repro.core import simulator, spans, traffic
 from repro.core.constants import DEFAULT_PHY, Fabric, PhyParams, SimParams
 from repro.core.metrics import Metrics, compute_metrics_batch
 from repro.core.routing import compute_routing
 from repro.core.topology import Topology, build_xcym
 
 HARMONIZED_DIMS = ("B", "S", "R", "K", "CS", "CR", "M", "P", "Y", "BK")
-
-# Cumulative points simulated via run_sweep_batched (per process).
-# benchmarks/run.py diffs this around each suite to report points/sec.
-POINTS_RUN = 0
 
 
 @functools.lru_cache(maxsize=64)
@@ -146,43 +142,48 @@ def run_sweep_batched(points: Sequence[SweepPoint],
     per-point program.  ``driver="monolithic"`` forces the fixed-length
     scan oracle (see ``simulator.run_batch``) — used by
     ``benchmarks/simspeed`` and the chunked-execution tests.
+
+    Host spans (``spans``): ``run_sweep_batched`` around ``sweep.build``,
+    ``sweep.harmonize``, then per group ``sweep.pack``, and per launch
+    ``run_batch`` and ``compute_metrics_batch`` (their own spans).
     """
-    global POINTS_RUN
-    POINTS_RUN += len(points)
-    built = [_build_point(p) for p in points]
-    natural = [simulator.pack_dims(topo, tt)
-               for topo, _, tt, _ in built]
+    with spans.span("run_sweep_batched"):
+        with spans.span("sweep.build"):
+            built = [_build_point(p) for p in points]
+        with spans.span("sweep.harmonize"):
+            natural = [simulator.pack_dims(topo, tt)
+                       for topo, _, tt, _ in built]
+            # group by N sources (cycle budgets are traced per-lane data
+            # and batch freely); harmonize pack dims within a group
+            groups: dict[tuple, list[int]] = {}
+            for i, (p, (_, _, tt, _)) in enumerate(zip(points, built)):
+                key = (tt.n_sources,)
+                groups.setdefault(key, []).append(i)
+            floors = [{d: max(natural[i][d] for i in idxs)
+                       for d in HARMONIZED_DIMS} for idxs in groups.values()]
 
-    # group by N sources (cycle budgets are traced per-lane data and batch
-    # freely); harmonize pack dims within a group
-    groups: dict[tuple, list[int]] = {}
-    for i, (p, (_, _, tt, _)) in enumerate(zip(points, built)):
-        key = (tt.n_sources,)
-        groups.setdefault(key, []).append(i)
-
-    results: list[Metrics | None] = [None] * len(points)
-    for idxs in groups.values():
-        floors = {d: max(natural[i][d] for i in idxs)
-                  for d in HARMONIZED_DIMS}
-        packed = {}
-        for i in idxs:
-            topo, rt, tt, _ = built[i]
-            packed[i] = simulator.pack(topo, rt, tt, points[i].phy,
-                                       points[i].sim, floors=floors,
-                                       phy_spec=points[i].phy_spec)
-        # harmonized dims should unify shapes; split defensively by shape
-        by_shape: dict[tuple, list[int]] = {}
-        for i in idxs:
-            by_shape.setdefault(packed[i].shape_key(), []).append(i)
-        for sub in by_shape.values():
-            pss = [packed[i] for i in sub]
-            st = simulator.run_batch(pss, cycles=cycles, devices=devices,
-                                     driver=driver)
-            ms = compute_metrics_batch(
-                pss, st, [built[i][3] for i in sub],
-                [built[i][2].offered_load for i in sub], cycles=cycles)
-            for i, m in zip(sub, ms):
-                results[i] = m
+        results: list[Metrics | None] = [None] * len(points)
+        for idxs, floor in zip(groups.values(), floors):
+            with spans.span("sweep.pack"):
+                packed = {}
+                for i in idxs:
+                    topo, rt, tt, _ = built[i]
+                    packed[i] = simulator.pack(topo, rt, tt, points[i].phy,
+                                               points[i].sim, floors=floor,
+                                               phy_spec=points[i].phy_spec)
+                # harmonized dims should unify shapes; split defensively
+                by_shape: dict[tuple, list[int]] = {}
+                for i in idxs:
+                    by_shape.setdefault(packed[i].shape_key(), []).append(i)
+            for sub in by_shape.values():
+                pss = [packed[i] for i in sub]
+                st = simulator.run_batch(pss, cycles=cycles, devices=devices,
+                                         driver=driver)
+                ms = compute_metrics_batch(
+                    pss, st, [built[i][3] for i in sub],
+                    [built[i][2].offered_load for i in sub], cycles=cycles)
+                for i, m in zip(sub, ms):
+                    results[i] = m
     return results  # type: ignore[return-value]
 
 

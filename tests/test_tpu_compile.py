@@ -106,3 +106,26 @@ def test_step_compiles_for_v5e(one_chip, kind):
     assert 0 < need < HBM_BYTES, mem
     # the donated state comes back in place
     assert mem.alias_size_in_bytes > 0, mem
+
+
+@pytest.mark.parametrize("driver", ["_run_one", "_run_mapped"])
+def test_step_scopes_survive_the_tpu_compile(one_chip, driver):
+    """The TPU compiler keeps every ideal step stage and driver scope in
+    the op_name metadata a device trace is split by."""
+    import re
+
+    from repro.core import spans
+    p = _point("ideal")
+    topo, rt, tt, _ = sweep._build_point(p)
+    ps = simulator.pack(topo, rt, tt, p.phy, p.sim)
+    st = simulator.init_state(*simulator._state_dims(ps),
+                              R=int(ps.ss.wl_serv_r.shape[0]))
+    lead = (LANES,) if driver == "_run_mapped" else ()
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        lead + x.shape, x.dtype, sharding=one_chip), (ps.ss, st))
+    hlo = getattr(simulator, driver).lower(
+        *args, ps.B, False, False, simulator.CHUNK_CYCLES, False,
+        False).compile().as_text()
+    named = {part for path in re.findall(r'op_name="([^"]*)"', hlo)
+             for part in path.split("/") if part in spans.SCOPES}
+    assert named == set(spans.SCOPES) - {"step.memory", "step.window"}
