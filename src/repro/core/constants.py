@@ -13,6 +13,8 @@ import enum
 # both engines' state layouts and the trace-table multicast masks
 # (traffic.from_trace), which must agree on the receiver-set width.
 WMAX = 16
+RXWMAX = 4       # max concurrent rx streams per WI (4-channel stacks, §IV)
+EJ_WAYS = 4      # parallel ejection channels at memory-stack switches
 
 
 class LinkClass(enum.IntEnum):

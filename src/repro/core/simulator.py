@@ -136,7 +136,25 @@ which upstream slot feeds it, so arrivals are gathers, not scatters.
 
 This matters because XLA:CPU executes scatters and segment ops as serial
 per-update loops that dominate the cycle cost; the gather/min formulation
-is several times faster per point.  The batched sweep engine
+is several times faster per point.
+
+The TPU is the other way round for gathers: it fetches the elements of a
+gather whose indices are known only at run time one at a time (7-11 ns
+each on a v5e), so the candidate-table gathers held most of a cycle.
+There the winner searches are dense instead: one masked ``min`` over
+every (buffer, vc) slot, limited to each target's contenders by static
+membership masks built in ``pack`` (``cand_w``/``cand_r``/``cand_s``, the
+same sets as ``cands``/``candr``); and a slot reads its target's winner,
+its multicast members or its target's free VCs by a one-hot compare and
+reduce over the small table's rows.  That is a few hundred thousand
+elements of vector work per search, which XLA:CPU runs several times
+slower than the gathers.  So ``core/arbitrate`` keeps both forms, exact
+and bitwise-equal, and picks one by the platform the step is lowered for
+(``jax.lax.platform_dependent``): gathers on the CPU, dense on the TPU.
+The remaining ``[B, V]``-sized reads of downstream state and of the
+winners' fields are gathers on both.
+
+The batched sweep engine
 (`run_batch`, used by ``sweep.run_sweep_batched``) runs N sweep points of
 the same bucket shape as one XLA launch (``lax.map`` over the stacked
 batch — bitwise-identical per-point programs) and shards groups across
@@ -179,10 +197,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import chunked, spans
+from repro.core import arbitrate, chunked, spans
 from repro.core.chunked import CHUNK_CYCLES
-from repro.core.constants import (WMAX, LinkClass, MacMode, PhyParams,
-                                  SimParams)
+from repro.core.constants import (EJ_WAYS, RXWMAX, WMAX, LinkClass, MacMode,
+                                  PhyParams, SimParams)
 from repro.core.routing import RoutingTables
 from repro.core.topology import Topology
 from repro.core.traffic import NO_PKT, TrafficTable
@@ -193,8 +211,6 @@ from repro.phy.retx import crc_fail as _crc_fail
 V = 8            # virtual channels per port (paper §IV)
 DEPTH = 16       # buffer depth in flits (paper §IV)
 DMAX = 12        # arrival-pipe depth >= max link latency
-RXWMAX = 4       # max concurrent rx streams per WI (4-channel stacks, §IV)
-EJ_WAYS = 4      # parallel ejection channels at memory-stack switches
 assert MEM_CH == EJ_WAYS, "pseudo-channels must map 1:1 onto ejection ways"
 
 
@@ -227,6 +243,10 @@ class SimStatic(NamedTuple):
     # arbitration candidate tables (static per topology)
     cands: jnp.ndarray       # [S, CS] buffer ids feeding each switch (pad B)
     candr: jnp.ndarray       # [W, CR] buffer ids able to tx to rx WI (pad B)
+    # the same sets as membership masks (dense arbitration, core/arbitrate)
+    cand_w: jnp.ndarray      # [B, B] bool: b' feeds the switch sending into b
+    cand_r: jnp.ndarray      # [W, B] bool: b' in candr[w]
+    cand_s: jnp.ndarray      # [S, B] bool: b' in cands[s]
     wi_sw: jnp.ndarray       # [W] switch of each WI (dummy S_pad-1)
     rxw: jnp.ndarray         # scalar int32: rx sub-channels per WI (>=1)
     # wireless
@@ -469,8 +489,9 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
     """Build the per-cycle transition function (shapes baked in).
 
     Scatter-free: arbitration winners are found by masked min over static
-    candidate tables using unique priority codes; delivery uses the
-    ``src_of`` inverse map (see module docstring).  ``mem_on`` (static)
+    candidate sets using unique priority codes (``core/arbitrate``:
+    gathered on the CPU, dense on the TPU); delivery uses the ``src_of``
+    inverse map (see module docstring).  ``mem_on`` (static)
     compiles the closed-loop memory path — bank model, reply gating,
     outstanding-transaction cap, per-slot packet lengths; ``phy_on``
     (static) compiles the lossy-channel ARQ path — per-link rates and
@@ -520,19 +541,6 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         warr = jnp.arange(WMAX, dtype=i32)
         rx_ids = jnp.clip(ss.rx0 + warr, 0, B - 1)           # [W]
 
-        # static candidate slot indices (flattened (buffer, vc) slots)
-        cw = ss.cands[jnp.clip(ss.b_src_sw, 0, S - 1)]       # [B, CS]
-        cw_ok = (cw < B)[:, :, None]                         # [B, CS, 1]
-        idx_w = jnp.clip(cw, 0, B - 1)[:, :, None] * V + varr[None, None, :]
-        cr_ok = (ss.candr < B)[:, :, None]                   # [W, CR, 1]
-        crc = jnp.clip(ss.candr, 0, B - 1)
-        idx_r = crc[:, :, None] * V + varr[None, None, :]    # [W, CR, V]
-        cs_ok = (ss.cands < B)[:, :, None]                   # [S, CS, 1]
-        csc = jnp.clip(ss.cands, 0, B - 1)
-        idx_s = csc[:, :, None] * V + varr[None, None, :]    # [S, CS, V]
-        tgt_ids = b_ids[:, None, None]                       # [B, 1, 1]
-        rx_tgt = (ss.rx0 + jnp.arange(WMAX, dtype=i32))[:, None, None]
-
         # ---- 1. arrivals -------------------------------------------------
         arrive = st.pipe[:, :, 0]
         rcvd = st.rcvd + arrive
@@ -551,10 +559,10 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         # everywhere, i.e. V/2 VCs per class as in classic escape schemes.
         free_mask = st.pkt_src < 0                               # [B, V]
         ob_c0 = jnp.clip(st.out_buf, 0, B - 1)
-        tgt_rx = ss.b_is_rx[ob_c0]                               # [B, V]
+        tgt_rx, free_tgt = arbitrate.target_free(ss, free_mask, ob_c0)
         allowed = jnp.where(tgt_rx[..., None], True,
                             jnp.where(st.phase2[..., None], ~classA, classA))
-        free_ok = free_mask[ob_c0] & allowed                     # [B, V, V]
+        free_ok = free_tgt & allowed                             # [B, V, V]
         has_free_c = free_ok.any(axis=-1)
         first_free_c = jnp.argmax(free_ok, axis=-1).astype(i32)  # [B, V]
         # multicast senders (group id set, air hop ahead): need a VC at
@@ -562,7 +570,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         # (phase2 set at rx install) never re-triggers multicast semantics.
         is_mc = (st.mc_id >= 0) & st.out_is_wl & ~st.phase2 & active
         mcid_c = jnp.clip(st.mc_id, 0, M - 1)
-        member = ss.mc_member[mcid_c]                            # [B, V, W]
+        member = arbitrate.member(ss, mcid_c)                    # [B, V, W]
         free_any_rx = free_mask[rx_ids].any(axis=1)              # [W]
         free_all_mc = jnp.where(member, free_any_rx[None, None, :],
                                 True).all(axis=-1)               # [B, V]
@@ -582,25 +590,15 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         need = need_uni | need_mc
         score = (flat2d - rot) % NC                              # unique/slot
         code = jnp.where(need, score * NCp1 + flat2d, BIGC)
-        codef = code.reshape(-1)
-        obf0 = st.out_buf.reshape(-1)
-        mcf0 = jnp.where(is_mc, st.mc_id, -1).reshape(-1)
+        mcf0 = jnp.where(is_mc, st.mc_id, -1)
 
         # winner (min code) per wired target buffer: contenders live at the
-        # buffers feeding the target's transmitting switch.  The gathered
-        # tensors go through optimization_barrier so XLA materializes them
-        # once instead of re-running the gather inside every fused consumer.
-        g_w = jax.lax.optimization_barrier((codef[idx_w], obf0[idx_w]))
-        m_w = cw_ok & (g_w[1] == tgt_ids)
-        win_code_w = jnp.where(m_w, g_w[0], BIGC).min(axis=(1, 2))
+        # buffers feeding the target's transmitting switch
+        win_code_w = arbitrate.wired_winners(ss, code, st.out_buf)
         # winner per wireless rx target: contenders at sender WI switches;
         # a multicast contends at every member receiver simultaneously
-        g_r = jax.lax.optimization_barrier(
-            (codef[idx_r], obf0[idx_r], mcf0[idx_r]))
-        memb_r = (g_r[2] >= 0) & ss.mc_member[
-            jnp.clip(g_r[2], 0, M - 1), warr[:, None, None]]
-        m_r = cr_ok & ((g_r[1] == rx_tgt) | memb_r)
-        win_code_r = jnp.where(m_r, g_r[0], BIGC).min(axis=(1, 2))
+        win_code_r = arbitrate.rx_winners(ss, code, st.out_buf, mcf0,
+                                          sub=False)
 
         rx_slot = jnp.clip(b_ids - ss.rx0, 0, WMAX - 1)
         win_code = jnp.where(ss.b_is_rx, win_code_r[rx_slot], win_code_w)
@@ -611,7 +609,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         win_all_mc = jnp.where(
             member, win_code_r[None, None, :] == code[:, :, None],
             True).all(axis=-1)                                   # [B, V]
-        win_uni = need_uni & (win_code[ob_c0] == code)
+        win_uni = need_uni & (arbitrate.take(win_code, ob_c0) == code)
         win_mc = need_mc & win_all_mc
         win = win_uni | win_mc
 
@@ -621,7 +619,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         # target side: suppress a partial multicast winner (nobody claims
         # that buffer this cycle), and deliver each member copy to its own
         # per-WI destination from the group table
-        w_mc = mcf0[wsrc]                                        # [B]
+        w_mc = mcf0.reshape(-1)[wsrc]                            # [B]
         w_group_ok = win_all_mc.reshape(-1)[wsrc]                # [B]
         has_win_eff = has_win & ((w_mc < 0) | w_group_ok)
         vfree_self = jnp.argmax(free_mask, axis=-1).astype(i32)  # [B]
@@ -692,7 +690,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         # a broadcast flit flies only when every member can accept it
         is_mc = (mc_id >= 0) & out_is_wl & ~phase2 & active      # [B, V]
         mcid_c = jnp.clip(mc_id, 0, M - 1)
-        member = ss.mc_member[mcid_c]                            # [B, V, W]
+        member = arbitrate.member(ss, mcid_c)                    # [B, V, W]
         srcof_rx = src_of[rx_ids]                                # [W, V]
         occ_rx = occ[rx_ids]
         infl_rx = inflight[rx_ids]
@@ -757,51 +755,32 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         elig = active & (occ > 0) & wl_ok & hold_ok \
             & (out_is_ej | ((out_vc >= 0) & (space > 0) & link_free))
         code2 = jnp.where(elig, score * NCp1 + flat2d, BIGC)
-        code2f = code2.reshape(-1)
         obf = out_buf.reshape(-1)
-        mcf = jnp.where(is_mc, mc_id, -1).reshape(-1)
+        mcf = jnp.where(is_mc, mc_id, -1)
 
         # wired-output winners: one flit per link per cycle
-        g2_w = jax.lax.optimization_barrier((code2f[idx_w], obf[idx_w]))
-        m2_w = cw_ok & (g2_w[1] == tgt_ids)
-        win2_w = jnp.where(m2_w, g2_w[0], BIGC).min(axis=(1, 2))
+        win2_w = arbitrate.wired_winners(ss, code2, out_buf)
         # multi-channel ejection: memory stacks sink `b_ej_ways` flits/cycle
         # (4-channel DRAM stacks, paper §IV); cores sink one.  A slot's
         # ejection "way" is vc % ways (memory requests: their channel);
         # one winner per (switch, way).
-        way_s = way_bv.reshape(-1)[idx_s]                        # [S, CS, V]
-        g_s = jax.lax.optimization_barrier(
-            (code2f[idx_s], out_is_ej.reshape(-1)[idx_s]))
-        m_ej = cs_ok & g_s[1]
-        win2_ej = jnp.where(
-            m_ej[None] & (way_s[None] == jnp.arange(EJ_WAYS)[:, None, None, None]),
-            g_s[0][None], BIGC).min(axis=(2, 3))                 # [EJ, S]
+        win2_ej = arbitrate.eject_winners(ss, code2, out_is_ej, way_bv)
         # wireless rx sub-channels: receiver w serves `rxw` concurrent
         # streams; a sender's stream is its WI id mod rxw.  A multicast
         # contends at every member receiver (on its own sub-channel) and
         # transmits only if it wins ALL of them — a single transmission
         # delivered to the whole receiver set.
         rxw = jnp.maximum(ss.rxw, 1)
-        g2_r = jax.lax.optimization_barrier(
-            (code2f[idx_r], obf[idx_r], mcf[idx_r]))
-        memb2_r = (g2_r[2] >= 0) & ss.mc_member[
-            jnp.clip(g2_r[2], 0, M - 1), warr[:, None, None]]
-        m2_r = cr_ok & ((g2_r[1] == rx_tgt) | memb2_r)           # [W, CR, V]
-        r_cand = (ss.b_wi[crc] % rxw)[:, :, None]                # [W, CR, 1]
-        win2_wl = jnp.where(
-            m2_r[None] & (r_cand[None] == jnp.arange(RXWMAX)[:, None, None, None]),
-            g2_r[0][None], BIGC).min(axis=(2, 3))                # [RXW, W]
+        win2_wl = arbitrate.rx_winners(ss, code2, out_buf, mcf, sub=True)
 
-        way_mine = way_bv                                        # [B, V]
         owo_s = jnp.clip(out_wo, 0, S - 1)                       # eject: switch
         owo_w = jnp.clip(out_wo, 0, WMAX - 1)                    # wl: dst WI
         r_mine = jnp.clip(ss.b_wi[:, None] % rxw, 0, RXWMAX - 1)
-        win2_mine = jnp.where(
-            out_is_ej, win2_ej[way_mine, owo_s],
-            jnp.where(out_is_wl, win2_wl[r_mine, owo_w], win2_w[ob_c]))
-        r_bv = jnp.broadcast_to(r_mine, (B, V))[:, :, None]      # [B, V, 1]
+        win2_mine = arbitrate.slot_winner(
+            win2_ej, win2_wl, win2_w, way_bv, owo_s, r_mine, owo_w, ob_c,
+            out_is_ej, out_is_wl)
         wl_all2 = jnp.where(
-            member, win2_wl[r_bv, warr[None, None, :]] == code2[:, :, None],
+            member, arbitrate.rx_row(win2_wl, r_mine, V) == code2[:, :, None],
             True).all(axis=-1)                                   # [B, V]
         fwd = elig & jnp.where(is_mc, wl_all2, code2 == win2_mine)
 
@@ -809,12 +788,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         # (and one WI total in single-channel mode); no-op for the crossbar
         # medium
         capped = fwd & out_is_wl & ss.wl_sender_cap
-        cap_code = jnp.where(capped, code2, BIGC).reshape(-1)
-        cT_ok = cs_ok[jnp.clip(ss.wi_sw, 0, S - 1)]              # [W, CS, 1]
-        idx_t = idx_s[jnp.clip(ss.wi_sw, 0, S - 1)]              # [W, CS, V]
-        win3 = jnp.where(
-            cT_ok, jax.lax.optimization_barrier(cap_code[idx_t]),
-            BIGC).min(axis=(1, 2))
+        win3 = arbitrate.cap_winners(ss, jnp.where(capped, code2, BIGC))
         my3 = jnp.where(ss.wl_single, win3.min(),
                         win3[jnp.clip(ss.b_wi, 0, WMAX - 1)][:, None])
         fwd &= ~capped | (code2 == my3)
@@ -1585,6 +1559,15 @@ def pack(topo: Topology, rt: RoutingTables, tt: TrafficTable,
         candr[w, :len(cr_lists[w])] = cr_lists[w]
     wi_sw = np.full(WMAX, S - 1, np.int32)
     wi_sw[:n_wi] = topo.wi_switch
+    # the same candidate sets as [target, buffer] masks; padding rows (the
+    # dummy switch S-1, WIs past n_wi) hold no candidate
+    cand_s = np.zeros((S, B), bool)
+    for s in range(topo.n_switches):
+        cand_s[s, in_bufs[s]] = True
+    cand_w = cand_s[b_src_sw]
+    cand_r = np.zeros((WMAX, B), bool)
+    for w in range(n_wi):
+        cand_r[w, cr_lists[w]] = True
 
     # routing lookup tables
     next_out = np.full((S, S), 0, np.int32)
@@ -1679,6 +1662,8 @@ def pack(topo: Topology, rt: RoutingTables, tt: TrafficTable,
         o_buf=jnp.asarray(o_buf), o_wo=jnp.asarray(o_wo),
         o_is_wl=jnp.asarray(o_is_wl), o_is_ej=jnp.asarray(o_is_ej),
         cands=jnp.asarray(cands), candr=jnp.asarray(candr),
+        cand_w=jnp.asarray(cand_w), cand_r=jnp.asarray(cand_r),
+        cand_s=jnp.asarray(cand_s),
         wi_sw=jnp.asarray(wi_sw), rxw=jnp.int32(RXW),
         n_wi=jnp.int32(n_wi), rx0=jnp.int32(rx0),
         inj_buf=jnp.asarray(Lw + np.arange(N, dtype=np.int32)),

@@ -108,13 +108,8 @@ def test_step_compiles_for_v5e(one_chip, kind):
     assert mem.alias_size_in_bytes > 0, mem
 
 
-@pytest.mark.parametrize("driver", ["_run_one", "_run_mapped"])
-def test_step_scopes_survive_the_tpu_compile(one_chip, driver):
-    """The TPU compiler keeps every ideal step stage and driver scope in
-    the op_name metadata a device trace is split by."""
-    import re
-
-    from repro.core import spans
+def _ideal_hlo(one_chip, driver: str) -> str:
+    """Optimized HLO text of the ideal 4C4M point's driver for a v5e."""
     p = _point("ideal")
     topo, rt, tt, _ = sweep._build_point(p)
     ps = simulator.pack(topo, rt, tt, p.phy, p.sim)
@@ -123,9 +118,38 @@ def test_step_scopes_survive_the_tpu_compile(one_chip, driver):
     lead = (LANES,) if driver == "_run_mapped" else ()
     args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         lead + x.shape, x.dtype, sharding=one_chip), (ps.ss, st))
-    hlo = getattr(simulator, driver).lower(
+    return getattr(simulator, driver).lower(
         *args, ps.B, False, False, simulator.CHUNK_CYCLES, False,
         False).compile().as_text()
+
+
+@pytest.mark.parametrize("driver", ["_run_one", "_run_mapped"])
+def test_step_scopes_survive_the_tpu_compile(one_chip, driver):
+    """The TPU compiler keeps every ideal step stage and driver scope in
+    the op_name metadata a device trace is split by."""
+    import re
+
+    from repro.core import spans
+    hlo = _ideal_hlo(one_chip, driver)
     named = {part for path in re.findall(r'op_name="([^"]*)"', hlo)
              for part in path.split("/") if part in spans.SCOPES}
     assert named == set(spans.SCOPES) - {"step.memory", "step.window"}
+
+
+def test_arbitration_has_no_large_gathers_on_the_tpu(one_chip):
+    """On the TPU the step finds arbitration winners and reads small
+    tables by dense compare-and-reduce (``core/arbitrate``): no gather
+    under ``step.vc_claim`` or ``step.forward`` of the ideal step holds
+    4,096 elements or more.  The TPU fetches a gather's elements one at a
+    time; with the gather forms this compile held 19 such gathers."""
+    import math
+    import re
+
+    hlo = _ideal_hlo(one_chip, "_run_one")
+    gathers = re.findall(
+        r"= \w+\[([\d,]*)\][^=]* gather\(.*?op_name=\"([^\"]*)\"", hlo)
+    assert gathers, "no gather found at all: the pattern no longer matches"
+    large = [(dims, name) for dims, name in gathers
+             if {"step.vc_claim", "step.forward"} & set(name.split("/"))
+             and math.prod(int(d) for d in dims.split(",") if d) >= 4096]
+    assert not large, large
