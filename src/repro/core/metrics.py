@@ -173,7 +173,10 @@ def compute_metrics_batch(pss: Sequence[PackedSim], st: SimState,
     ``metrics.energy`` (the jitted energy terms, read to the host) and
     ``metrics.lanes`` (the per-lane reads and float math), with counters
     ``budget_lane_cycles`` (Σ ``cycles_run``) and ``executed_lane_cycles``
-    (Σ ``drain_cycle``).
+    (Σ ``drain_cycle``); over the lossy channel also ``air_flits``
+    (Σ ``wl_pair_flits``: flits put on the air, failing attempts
+    included) and ``air_fail_flits`` (Σ ``wl_fail_flits``: those of
+    attempts that failed their CRC).
     """
     with spans.span("compute_metrics_batch") as counters:
         with spans.span("metrics.energy"):
@@ -194,6 +197,10 @@ def compute_metrics_batch(pss: Sequence[PackedSim], st: SimState,
                                 cycles)
         counters["budget_lane_cycles"] = sum(m.cycles_run for m in out)
         counters["executed_lane_cycles"] = sum(m.drain_cycle for m in out)
+        if pss[0].phy_on:     # phy_on splits batches: all lanes or none
+            counters["air_flits"] = int(np.asarray(st.wl_pair_flits).sum())
+            counters["air_fail_flits"] = int(
+                np.asarray(st.wl_fail_flits).sum())
     return out
 
 

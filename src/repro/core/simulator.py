@@ -94,7 +94,12 @@ of ``(seed, packet, attempt)`` against the link's PER threshold
 (``wl_fail_flits`` counts their wasted flits); a NACK on the tail
 rewinds the sender for the next attempt, and a packet failing
 ``max_retx`` attempts is dropped (sender slot and receiver VC freed,
-``pkts_dropped``).  Receivers are store-and-forward under ``rx_hold``:
+``pkts_dropped``).  A unicast air flit reaches the receiver VC its
+sender names, found through the (sub-channel, receiver) air winners
+rather than ``src_of``: when a drifting channel moves a link's PER
+threshold inside an attempt, a receiver can forward its packet and hand
+the VC on while the old sender still streams into it (as the reference
+engine's scatter does).  Receivers are store-and-forward under ``rx_hold``:
 an rx-buffer slot neither claims its downstream VC nor forwards until
 the whole packet has arrived (the CRC check completes at the tail).
 
@@ -491,7 +496,9 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
     Scatter-free: arbitration winners are found by masked min over static
     candidate sets using unique priority codes (``core/arbitrate``:
     gathered on the CPU, dense on the TPU); delivery uses the ``src_of``
-    inverse map (see module docstring).  ``mem_on`` (static)
+    inverse map, except that with ``phy_on`` unicast air flits reach the
+    rx buffers through the (sub-channel, receiver) air winners (see
+    module docstring).  ``mem_on`` (static)
     compiles the closed-loop memory path — bank model, reply gating,
     outstanding-transaction cap, per-slot packet lengths; ``phy_on``
     (static) compiles the lossy-channel ARQ path — per-link rates and
@@ -719,6 +726,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         # store-and-forward receivers: rx slots forward only whole packets
         hold_ok = ~(ss.rx_hold & ss.b_is_rx[:, None]) | whole
         if phy_on:
+            stage("step.phy")
             # lossy PHY: the sender holds the whole packet (ARQ needs it
             # for retransmission), the (src, dst) WI pair paces at the
             # link's selected rate, and the current attempt's CRC
@@ -752,6 +760,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             # so batched and single-point runs draw identical outcomes
             uid = psrc_c * 65536 + pidx_c
             fail_bv = _crc_fail(ss.phy_seed, uid, attempt, perq_bv)
+            stage("step.forward")
         elig = active & (occ > 0) & wl_ok & hold_ok \
             & (out_is_ej | ((out_vc >= 0) & (space > 0) & link_free))
         code2 = jnp.where(elig, score * NCp1 + flat2d, BIGC)
@@ -796,6 +805,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
 
         sent = sent + fwd.astype(i32)
         if phy_on:
+            stage("step.phy")
             # CRC check on the tail of every air attempt: NACK rewinds
             # the sender (the whole packet is still buffered), the
             # bounded-ARQ loser is dropped — sender slot and the claimed
@@ -819,6 +829,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
                 .astype(i32)
             wl_drop_flits = st.wl_drop_flits + post * jnp.where(
                 drop, plen_bv * member_cnt, 0).sum().astype(i32)
+            stage("step.forward")
         else:
             tail = fwd & (sent >= plen_bv)
             wl_nacks, wl_pkts = st.wl_nacks, st.wl_pkts
@@ -948,13 +959,17 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         stage("step.forward")
         # non-eject: deliver downstream via the src_of inverse map — each
         # target (buffer, vc) gathers from the unique upstream slot feeding
-        # it (identity-checked against out_buf/out_vc to survive slot reuse)
+        # it (identity-checked against out_buf/out_vc to survive slot reuse).
+        # Over the lossy channel, unicast air flits into rx buffers come
+        # instead from the (sub-channel, receiver) air winners (below).
         if phy_on:
+            stage("step.phy")
             # per-link rate: serialization and control-packet time follow
             # the (src, dst) WI pair's selected rate from the PHY table
             first_wl = first_wl_phy
             ctrl_bv = jnp.maximum(1, ss.ctrl_flits * serv_wl_bv)
             lat_wl_bv = (ss.lat_wl - ss.serv_wl) + serv_wl_bv
+            stage("step.forward")
         else:
             first_wl = is_wl_fwd & (sent == 1)   # header => control packet
             ctrl_bv = ss.ctrl_cycles
@@ -976,13 +991,56 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         ident_mc = (src_of >= 0) & is_mc_f[sv] & ss.b_is_rx[:, None] \
             & (mc_id >= 0) & (mc_id.reshape(-1)[sv] == mc_id)
         ident = ident_uni | ident_mc
-        incoming_any = ident & fwd.reshape(-1)[sv]               # [B, V]
         if phy_on:
+            # unicast air flits reach the rx buffers sender-side (below)
+            feed = ident_mc | (ident_uni & ~ss.b_is_rx[:, None])
+        else:
+            feed = ident
+        incoming_any = feed & fwd.reshape(-1)[sv]                # [B, V]
+        busy_until = st.busy_until
+        if phy_on:
+            stage("step.phy")
             # failing attempts occupy the channel/receiver but deliver
             # nothing; the dropped packet's receiver VC is freed below
             deliver = fwd & ~(out_is_wl & fail_bv)
-            incoming = ident & deliver.reshape(-1)[sv]
-            rx_dropped = ident & drop.reshape(-1)[sv]
+            incoming = feed & deliver.reshape(-1)[sv]
+            # a unicast air flit lands in the receiver VC its sender
+            # names (out_buf, out_vc), whoever holds that VC now: src_of
+            # names only its newest feeder (module docstring, Lossy PHY).
+            # Every such sender is the air winner of its (sub-channel,
+            # receiver) cell.
+            def at_rx(x):    # [W, ...] per receiver -> its rx buffer's row
+                pad = jnp.zeros((B + WMAX,) + x.shape[1:], x.dtype)
+                at = (ss.rx0,) + (jnp.int32(0),) * (x.ndim - 1)
+                return jax.lax.dynamic_update_slice(pad, x, at)[:B]
+
+            wa_ok = win2_wl < BIGC                               # [RXW, W]
+            wa = jnp.where(wa_ok, win2_wl % NCp1, 0)
+
+            def at_wa(x):    # the air winners' field, [RXW, W]
+                return x.reshape(-1)[wa]
+
+            on_air = wa_ok & at_wa(fwd) & at_wa(out_is_wl)
+            fail_a = at_wa(fail_bv)
+            drop_a = at_wa(drop)
+            serv_a = at_wa(serv_t)
+            air = on_air & ~at_wa(is_mc)
+            air_in = air & ~fail_a
+            air_vc = at_wa(ovc_c)[:, :, None] == varr            # [RXW, W, V]
+            air_d = jnp.clip(at_wa(lat_t) - 1, 0, DMAX - 1)
+            air_pipe = (air_in[:, :, None, None] & air_vc[:, :, :, None]
+                        & (air_d[:, :, None, None] == jnp.arange(DMAX))
+                        ).sum(axis=0).astype(pipe.dtype)         # [W, V, D]
+            pipe = pipe + at_rx(air_pipe)
+            air_n = at_rx(air_in.sum(axis=0).astype(i32))        # [B]
+            rx_dropped = (ident_mc & drop.reshape(-1)[sv]) | at_rx(
+                ((air & drop_a)[:, :, None] & air_vc).any(axis=0))
+            air_ser = air & ss.wl_rx_busy
+            busy_until = jnp.where(
+                at_rx(air_ser.any(axis=0)),
+                t + at_rx(jnp.where(air_ser, serv_a, 0).sum(axis=0)),
+                busy_until)
+            stage("step.forward")
         else:
             incoming = incoming_any
         d_in = jnp.clip(lat_t.reshape(-1)[sv] - 1, 0, DMAX - 1)
@@ -994,7 +1052,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         serv_in = serv_t.reshape(-1)[sv]
         busy_until = jnp.where(
             ser_in.any(axis=1),
-            t + jnp.where(ser_in, serv_in, 0).sum(axis=1), st.busy_until)
+            t + jnp.where(ser_in, serv_in, 0).sum(axis=1), busy_until)
         wl_busy_until = jnp.where(
             is_wl_fwd.any(),
             t + (jnp.where(is_wl_fwd, serv_t, 0)).max(), st.wl_busy_until)
@@ -1014,6 +1072,10 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         wl_rate_flits = st.wl_rate_flits
         wl_rate_fail = st.wl_rate_fail
         if phy_on:
+            stage("step.phy")
+            # the unicast air flits delivered sender-side (above)
+            counts_into = counts_into + post * air_n
+            wl_rx_flits = wl_rx_flits + post * air_n.sum()
             # per-(src WI, dst WI) pacing + energy counters, scatter-free:
             # the (sub-channel, receiver) air winner is unique, so each
             # pair sees at most one transmission per cycle — a masked
@@ -1022,19 +1084,21 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             # appears in EVERY member receiver's column; the air/pair
             # accounting anchors it on the routed (sender, anchor) pair
             # once — the own-column check is a no-op for unicast, whose
-            # winning column IS its destination.
+            # winning column IS its destination.  Sender WI ws reads the
+            # winners' fields above on its own sub-channel ws % rxw.
             ws_ids = jnp.arange(WMAX, dtype=i32)[:, None]        # [W, 1]
-            r_ids = jnp.clip(ws_ids % rxw, 0, RXWMAX - 1)
-            w2 = win2_wl[r_ids, warr[None, :]]                   # [W, W]
-            v2 = w2 < BIGC
-            slot2 = jnp.where(v2, w2 % NCp1, 0)
-            txp = v2 & fwd.reshape(-1)[slot2] \
-                & out_is_wl.reshape(-1)[slot2] \
-                & (ss.b_wi[slot2 // V] == ws_ids) \
-                & (wd_bv.reshape(-1)[slot2] == warr[None, :])
-            failp = txp & fail_bv.reshape(-1)[slot2]
-            pair_busy = jnp.where(txp, t + serv_t.reshape(-1)[slot2],
-                                  st.pair_busy)
+            mine = (jnp.clip(ws_ids % rxw, 0, RXWMAX - 1)
+                    == jnp.arange(RXWMAX))[:, :, None]           # [W, RXW, 1]
+
+            def by_sender(x):    # [RXW, W] -> [W, W]
+                if x.dtype == jnp.bool_:
+                    return (mine & x[None]).any(axis=1)
+                return jnp.where(mine, x[None], 0).sum(axis=1)
+
+            txp = by_sender(on_air & (at_wa(wd_bv) == warr)) \
+                & (by_sender(ss.b_wi[wa // V]) == ws_ids)
+            failp = txp & by_sender(fail_a)
+            pair_busy = jnp.where(txp, t + by_sender(serv_a), st.pair_busy)
             wl_pair_flits = st.wl_pair_flits + post * txp.astype(i32)
             wl_fail_flits = st.wl_fail_flits + post * failp.astype(i32)
             if living:
@@ -1059,9 +1123,9 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
                 # drop is an air-pair winner, so the [W, W] grid sees
                 # each one exactly once (gather style; the reference
                 # engine scatters the same updates).
-                d_on = txp & drop.reshape(-1)[slot2]             # [W, W]
-                nd = jnp.clip(pkt_src.reshape(-1)[slot2], 0, Nn - 1)
-                kd = jnp.clip(pkt_idx.reshape(-1)[slot2], 0, Kk - 1)
+                d_on = txp & by_sender(drop_a)                   # [W, W]
+                nd = jnp.clip(by_sender(at_wa(pkt_src)), 0, Nn - 1)
+                kd = jnp.clip(by_sender(at_wa(pkt_idx)), 0, Kk - 1)
                 opd = jnp.where(d_on, ss.mem_op[nd, kd], 0)
                 is_rqd = (opd == 1) | (opd == 2)
                 is_repd = (opd == 3) | (opd == 4)
@@ -1081,6 +1145,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
                 # reply means the requester never sees its data
                 mem_drop_reads = mem_drop_reads + post * (
                     d_on & ((opd == 1) | (opd == 3))).sum().astype(i32)
+            stage("step.forward")
         else:
             pair_busy = st.pair_busy
             wl_pair_flits = st.wl_pair_flits

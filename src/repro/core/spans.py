@@ -30,9 +30,12 @@ MAXLEN = 1 << 14           # records kept; the oldest fall out first
 
 # device stage scopes: the stages of ``simulator.make_step`` as its code
 # numbers them, then the living-channel window update (which runs first in
-# a cycle), and the scopes of ``chunked.run_chunked``
+# a cycle), the lossy channel's work inside forwarding (per-link tables,
+# pacing, CRC, ARQ rewind and drop, air delivery), and the scopes of
+# ``chunked.run_chunked``
 STEP_STAGES = ("step.arrive", "step.vc_claim", "step.forward", "step.phase",
-               "step.memory", "step.inject", "step.rx_sleep", "step.window")
+               "step.memory", "step.inject", "step.rx_sleep", "step.window",
+               "step.phy")
 DRIVER_SCOPES = ("driver.cycle", "driver.drain_check", "driver.finalize")
 SCOPES = STEP_STAGES + DRIVER_SCOPES
 

@@ -1,7 +1,9 @@
 """Host spans, counters and device stage scopes of the sweep path."""
+import contextlib
 import re
 
 import jax
+import numpy as np
 import pytest
 
 from repro.core import simulator, spans, sweep
@@ -124,7 +126,8 @@ def test_compiled_step_carries_every_ideal_scope(driver):
 
 
 @pytest.mark.parametrize("kind,stage", [("mem_on", "step.memory"),
-                                        ("living", "step.window")])
+                                        ("living", "step.window"),
+                                        ("living", "step.phy")])
 def test_optional_stages_are_scoped(kind, stage):
     from repro.memory import MemSweepSpec
     from repro.phy import PhySweepSpec
@@ -144,3 +147,49 @@ def test_optional_stages_are_scoped(kind, stage):
              for part in part.split("/")}
     assert stage in names
     assert IDEAL_SCOPES <= names
+
+
+def test_stage_scopes_change_metadata_only(monkeypatch):
+    """Scopes name operations and nothing else: the ideal step's lowering,
+    debug info aside, is the same with every scope turned off."""
+    ps, st = _ideal_args()
+
+    def lowered():
+        # a new function each time: a jit of the same one reuses its trace
+        fn = jax.jit(lambda *a: simulator._run_one.__wrapped__(*a),
+                     static_argnums=(2, 3, 4, 5, 6, 7))
+        return fn.lower(ps.ss, st, ps.B, False, False,
+                        simulator.CHUNK_CYCLES, False, False).as_text()
+
+    scoped = lowered()
+    monkeypatch.setattr(spans, "scope",
+                        lambda name: contextlib.nullcontext())
+    assert lowered() == scoped
+
+
+def test_air_counters_over_the_lossy_channel():
+    """``compute_metrics_batch`` counts the flits put on the air and those
+    of failing attempts over the lossy channel's lanes (ideal lanes keep
+    neither counter: ``_check_call``)."""
+    from repro.core.metrics import compute_metrics_batch
+    from repro.phy import PhySweepSpec
+    pss = []
+    for policy in ("adaptive", "fixed:0"):
+        p = SweepPoint(4, 4, Fabric.WIRELESS, load=0.5, sim=SIM,
+                       phy_spec=PhySweepSpec(link_budget_db=16.0,
+                                             policy=policy, max_retx=3,
+                                             drift_amp_db=4.0))
+        topo, rt, tt, _ = sweep._build_point(p)
+        pss.append(simulator.pack(topo, rt, tt, p.phy, p.sim,
+                                  phy_spec=p.phy_spec))
+    st = simulator.run_batch(pss)
+    spans.reset()
+    compute_metrics_batch(pss, st, ["adaptive", "fixed:0"], [0.5, 0.5])
+    (rec,) = [r for r in spans.snapshot()
+              if r.name == "compute_metrics_batch"]
+    spans.reset()
+    air = int(np.asarray(st.wl_pair_flits).sum())
+    fail = int(np.asarray(st.wl_fail_flits).sum())
+    assert rec.attrs["air_flits"] == air
+    assert rec.attrs["air_fail_flits"] == fail
+    assert 0 < fail < air
