@@ -159,6 +159,18 @@ and bitwise-equal, and picks one by the platform the step is lowered for
 The remaining ``[B, V]``-sized reads of downstream state and of the
 winners' fields are gathers on both.
 
+Injection moves values between the sources and their injection buffers
+as one block, with no gather and one form on both backends: ``pack``
+lays the buffers out at ``[Lw, Lw+N)`` (source n feeds buffer Lw + n),
+so a ``dynamic_update_slice`` at ``Lw = inj_buf[0]`` spreads a
+per-source vector onto ``[B]`` and a ``dynamic_slice`` reads the
+block's rows back.  The TPU expands a small gather ``x[nb]`` into one
+scalar extract and ``[B]``-wide select per source, 64 to a read, which
+held about a third of the ideal step; the slices are cheap on XLA:CPU
+too.  ``Lw`` is traced per lane, so lanes of different fabrics share a
+program (a ``vmap`` over lanes would turn its per-lane start into a
+scatter).
+
 The batched sweep engine
 (`run_batch`, used by ``sweep.run_sweep_batched``) runs N sweep points of
 the same bucket shape as one XLA launch (``lax.map`` over the stacked
@@ -1170,8 +1182,20 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         n_ar = jnp.arange(N, dtype=i32)
         qh = jnp.clip(st.q_head, 0, K - 1)
         birth_n = ss.births[n_ar, qh]
-        ib = ss.inj_buf                                         # [N]
-        ifree = (pkt_src[ib] < 0) & classA[None, :]             # [N, V]
+        # source n feeds buffer Lw + n (pack): the two trade values as that
+        # block, at the traced Lw; rows outside it are masked by n_valid
+        lw = ss.inj_buf[0]
+        nb = jnp.clip(ss.inj_src, 0, N - 1)                     # [B]
+        n_valid = ss.inj_src >= 0
+
+        def blk(x):                                  # [B, ...] -> [N, ...]
+            return jax.lax.dynamic_slice_in_dim(x, lw, N)
+
+        def gn(x):                       # [N] -> [B]: x[n] at Lw + n, else 0
+            return jax.lax.dynamic_update_slice_in_dim(
+                jnp.zeros(B, x.dtype), x, lw, 0)
+
+        ifree = (blk(pkt_src) < 0) & classA[None, :]            # [N, V]
         ihas = ifree.any(axis=1)
         ivc = jnp.argmax(ifree, axis=1).astype(i32)
         # phase gate: a packet injects only once its phase is open
@@ -1193,14 +1217,6 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             dst_raw < 0, ss.mc_route[jnp.clip(mcv_n, 0, M - 1)], dst_raw)
         r_oo, r_ob, r_owo, r_owl, r_oej = _route_fields(
             ss, ss.src_switch, dst_n)
-
-        # target side: injection buffers map 1:1 to sources (static inj_src)
-        nb = jnp.clip(ss.inj_src, 0, N - 1)                     # [B]
-        n_valid = ss.inj_src >= 0
-
-        def gn(x):
-            return x[nb]                                        # [B]
-
         icl = (n_valid & gn(can_new))[:, None] & (gn(ivc)[:, None] == vcol)
 
         def iupd(old, val_n):
@@ -1238,8 +1254,8 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         # push one flit/cycle/core while there is space (cores write straight
         # into their injection buffer — no pipe, so no src_of either)
         iv_c = jnp.clip(inj_vc, 0, V - 1)
-        iocc = rcvd[ib, iv_c] - sent[ib, iv_c]
-        can_push = (inj_vc >= 0) & (iocc < ss.b_depth[ib])
+        iocc = jnp.where(iv_c[:, None] == vcol, blk(rcvd - sent), 0).sum(1)
+        can_push = (inj_vc >= 0) & (iocc < blk(ss.b_depth))
         pushc = (n_valid & gn(can_push))[:, None] & (gn(iv_c)[:, None] == vcol)
         rcvd = rcvd + pushc.astype(i32)
         inj_pushed = inj_pushed + can_push.astype(inj_pushed.dtype)

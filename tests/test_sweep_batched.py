@@ -88,3 +88,56 @@ def test_pack_floors_only_raise_dims():
     assert int(a.flits_del) == int(b.flits_del)
     assert int(a.pkts_del) == int(b.pkts_del)
     assert float(a.lat_sum) == float(b.lat_sum)
+
+
+def _packed(case: str) -> simulator.PackedSim:
+    from repro.core import sweep
+    from repro.memory import MemSweepSpec
+    fabric = {"substrate": Fabric.SUBSTRATE,
+              "interposer": Fabric.INTERPOSER}.get(case, Fabric.WIRELESS)
+    mem = MemSweepSpec(load=0.3) if case == "mem_on" else None
+    p = SweepPoint(4, 4, fabric, load=0.3, sim=SIM, mem=mem)
+    topo, rt, tt, _ = sweep._build_point(p)
+    ps = simulator.pack(topo, rt, tt, p.phy, p.sim)
+    if case == "floors":
+        ps = simulator.pack(topo, rt, tt, p.phy, p.sim,
+                            floors={k: v + 64 for k, v in ps.dims.items()})
+    return ps
+
+
+@pytest.mark.parametrize("case", ["substrate", "interposer", "wireless",
+                                  "mem_on", "floors"])
+def test_pack_lays_injection_buffers_out_as_one_block(case):
+    """The step moves values between sources and their injection buffers
+    as one block at ``inj_buf[0]``: source n feeds buffer Lw + n, and no
+    other buffer names a source."""
+    ps = _packed(case)
+    assert ps.mem_on == (case == "mem_on")
+    if case == "floors":
+        assert ps.B >= _packed("wireless").B + 64
+    Lw, N = ps.Lw, ps.n_inj
+    inj_src = np.asarray(ps.ss.inj_src)
+    assert inj_src.shape == (ps.B,) and Lw + N <= ps.B
+    assert np.array_equal(inj_src[Lw:Lw + N], np.arange(N))
+    assert (np.delete(inj_src, np.arange(Lw, Lw + N)) == -1).all()
+    assert np.array_equal(np.asarray(ps.ss.inj_buf), Lw + np.arange(N))
+
+
+def test_batched_mixed_fabrics_share_one_launch(monkeypatch):
+    """The three fabrics place their injection block at different Lw, yet
+    ride in one launch, and each lane equals its own run_point."""
+    launches = []
+    run_batch = simulator.run_batch
+
+    def spy(pss, **kw):
+        launches.append([int(ps.ss.inj_buf[0]) for ps in pss])
+        return run_batch(pss, **kw)
+
+    monkeypatch.setattr(simulator, "run_batch", spy)
+    pts = [SweepPoint(4, 4, fab, load=0.6, sim=SIM) for fab in Fabric]
+    batched = run_sweep_batched(pts)
+    assert len(launches) == 1 and len(set(launches[0])) == 3, launches
+    for p, b in zip(pts, batched):
+        s = run_point(p.n_chips, p.n_mem, p.fabric, p.load, p_mem=p.p_mem,
+                      sim=p.sim)
+        _assert_metrics_equal(b, s)

@@ -154,3 +154,23 @@ def test_arbitration_has_no_large_gathers_on_the_tpu(one_chip):
              if {"step.vc_claim", "step.forward"} & set(name.split("/"))
              and math.prod(int(d) for d in dims.split(",") if d) >= 4096]
     assert not large, large
+
+
+def test_injection_has_no_expanded_gathers_on_the_tpu(one_chip):
+    """Sources and their injection buffers trade values as one block at
+    ``inj_buf[0]`` (``make_step``), not by gathers through ``inj_src``:
+    the TPU expands each ``[N] -> [B]`` gather into 64 scalar extracts
+    selected into a fusion of 64 or more operands.  With the gathers this
+    compile held 13 such fusions and 4,898 ops under ``step.inject``."""
+    import re
+
+    hlo = _ideal_hlo(one_chip, "_run_one")
+    inject = [line for line in hlo.splitlines()
+              if "step.inject" in "".join(
+                  re.findall(r'op_name="([^"]*)"', line)).split("/")]
+    wide = [(m.group(1), m.group(2).count("%")) for m in (
+        re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = .*? fusion\((.*?)\), kind=",
+                 line) for line in inject) if m]
+    assert wide, "no fusion found at all: the pattern no longer matches"
+    assert not [w for w in wide if w[1] >= 64], wide
+    assert len(inject) < 1500, len(inject)
