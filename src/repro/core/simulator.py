@@ -127,12 +127,13 @@ birth that will never come.
 
 Execution strategy (this file's performance core)
 -------------------------------------------------
-The cycle step is written entirely with *static-index gathers, masked
-min-reductions and elementwise ops* — no scatters and no segment ops.
+The cycle step is written entirely with *masked min-reductions, small
+gathers and elementwise ops* — no scatters and no segment ops.
 Arbitration (VC claims, output ports, the wireless sender cap) is resolved
-target-side over **static candidate tables** built at pack time from the
-topology: ``cands[s]`` lists the buffers feeding switch ``s`` and
-``candr[w]`` the buffers that can transmit to wireless receiver ``w``.
+target-side over **static candidate masks** built at pack time from the
+topology: ``cand_s[s]`` marks the buffers feeding switch ``s``,
+``cand_w[b]`` those feeding the switch that sends into buffer ``b`` and
+``cand_r[w]`` the buffers that can transmit to wireless receiver ``w``.
 Each contending slot gets a unique priority code
 ``score * (B*V+1) + slot_id`` (scores are a rotating permutation, so codes
 never tie) and the winner per target is a masked ``min``.  Flit delivery is
@@ -140,24 +141,17 @@ inverted the same way through ``SimState.src_of``: each (buffer, vc) knows
 which upstream slot feeds it, so arrivals are gathers, not scatters.
 
 This matters because XLA:CPU executes scatters and segment ops as serial
-per-update loops that dominate the cycle cost; the gather/min formulation
-is several times faster per point.
+per-update loops that dominate the cycle cost.
 
-The TPU is the other way round for gathers: it fetches the elements of a
-gather whose indices are known only at run time one at a time (7-11 ns
-each on a v5e), so the candidate-table gathers held most of a cycle.
-There the winner searches are dense instead: one masked ``min`` over
-every (buffer, vc) slot, limited to each target's contenders by static
-membership masks built in ``pack`` (``cand_w``/``cand_r``/``cand_s``, the
-same sets as ``cands``/``candr``); and a slot reads its target's winner,
-its multicast members or its target's free VCs by a one-hot compare and
-reduce over the small table's rows.  That is a few hundred thousand
-elements of vector work per search, which XLA:CPU runs several times
-slower than the gathers.  So ``core/arbitrate`` keeps both forms, exact
-and bitwise-equal, and picks one by the platform the step is lowered for
-(``jax.lax.platform_dependent``): gathers on the CPU, dense on the TPU.
-The remaining ``[B, V]``-sized reads of downstream state and of the
-winners' fields are gathers on both.
+The TPU, in turn, fetches the elements of a gather whose indices are
+known only at run time one at a time (7-11 ns each on a v5e).  So the
+winner searches and small-table lookups (``core/arbitrate``) have one
+form on both backends, with no such gather: a search is one masked
+``min`` over every (buffer, vc) slot, limited to each target's
+contenders by its mask; a slot reads its target's winner, its multicast
+members or its target's free VCs by a one-hot compare and reduce over
+the small table's rows.  The remaining ``[B, V]``-sized reads of
+downstream state and of the winners' fields are gathers.
 
 Injection moves values between the sources and their injection buffers
 as one block, with no gather and one form on both backends: ``pack``
@@ -257,13 +251,10 @@ class SimStatic(NamedTuple):
     #                          eject -> switch id, wireless -> dst WI id
     o_is_wl: jnp.ndarray     # [R] bool wireless pair link
     o_is_ej: jnp.ndarray     # [R] bool ejection
-    # arbitration candidate tables (static per topology)
-    cands: jnp.ndarray       # [S, CS] buffer ids feeding each switch (pad B)
-    candr: jnp.ndarray       # [W, CR] buffer ids able to tx to rx WI (pad B)
-    # the same sets as membership masks (dense arbitration, core/arbitrate)
+    # arbitration candidate masks (static per topology, core/arbitrate)
     cand_w: jnp.ndarray      # [B, B] bool: b' feeds the switch sending into b
-    cand_r: jnp.ndarray      # [W, B] bool: b' in candr[w]
-    cand_s: jnp.ndarray      # [S, B] bool: b' in cands[s]
+    cand_r: jnp.ndarray      # [W, B] bool: b' can transmit to rx WI w
+    cand_s: jnp.ndarray      # [S, B] bool: b' feeds switch s
     wi_sw: jnp.ndarray       # [W] switch of each WI (dummy S_pad-1)
     rxw: jnp.ndarray         # scalar int32: rx sub-channels per WI (>=1)
     # wireless
@@ -506,11 +497,10 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
     """Build the per-cycle transition function (shapes baked in).
 
     Scatter-free: arbitration winners are found by masked min over static
-    candidate sets using unique priority codes (``core/arbitrate``:
-    gathered on the CPU, dense on the TPU); delivery uses the ``src_of``
-    inverse map, except that with ``phy_on`` unicast air flits reach the
-    rx buffers through the (sub-channel, receiver) air winners (see
-    module docstring).  ``mem_on`` (static)
+    candidate sets using unique priority codes (``core/arbitrate``);
+    delivery uses the ``src_of`` inverse map, except that with ``phy_on``
+    unicast air flits reach the rx buffers through the (sub-channel,
+    receiver) air winners (see module docstring).  ``mem_on`` (static)
     compiles the closed-loop memory path — bank model, reply gating,
     outstanding-transaction cap, per-slot packet lengths; ``phy_on``
     (static) compiles the lossy-channel ARQ path — per-link rates and
@@ -1476,28 +1466,12 @@ def pack_dims(topo: Topology, tt: TrafficTable,
     n_inj = tt.n_sources
     n_wi = topo.n_wi
     Wp = len(topo.wl_pairs)
-    # buffers into each switch: wired link dsts + injection dsts + rx dsts
-    b_dst_real = np.concatenate([
-        topo.link_dst.astype(np.int64),
-        tt.src_switch.astype(np.int64),
-        topo.wi_switch.astype(np.int64)])
-    indeg = np.bincount(b_dst_real, minlength=topo.n_switches)
-    cr_max = 0
-    if n_wi:
-        senders = [set() for _ in range(n_wi)]
-        for src_wi, dst_wi in topo.wl_pairs:
-            senders[int(dst_wi)].add(int(topo.wi_switch[int(src_wi)]))
-        # buffer lists are disjoint per switch, so candidate counts add up
-        cr_max = max((int(sum(indeg[s] for s in sw)) for sw in senders),
-                     default=0)
     dram = getattr(tt, "dram", None)
     return {
         "B": _bucket(Lw + n_inj + n_wi, b_bucket),
         "S": _bucket(topo.n_switches + 1, s_bucket),
         "R": _bucket(Lw + Wp + topo.n_switches, r_bucket),
         "K": _bucket(tt.k, k_bucket),
-        "CS": _bucket(int(indeg.max(initial=1)), 4),
-        "CR": _bucket(max(cr_max, 1), 16),
         "M": _bucket(getattr(tt, "n_mc", 0), 8),
         "P": _bucket(getattr(tt, "n_phases", 0), 8),
         "Y": _bucket(topo.n_mem, 4),
@@ -1512,8 +1486,8 @@ def pack(topo: Topology, rt: RoutingTables, tt: TrafficTable,
          phy_spec=None) -> PackedSim:
     """Pack a (topology, routing, traffic) point into padded device arrays.
 
-    ``floors`` maps dim names (``B``, ``S``, ``R``, ``K``, ``CS``, ``CR``)
-    to minimum padded sizes, letting heterogeneous points be harmonized
+    ``floors`` maps dim names (``sweep.HARMONIZED_DIMS``) to minimum
+    padded sizes, letting heterogeneous points be harmonized
     onto one bucket shape so they can share an XLA compile *and* a batch
     (see ``sweep.run_sweep_batched``).  Padding is semantically inert.
 
@@ -1615,16 +1589,11 @@ def pack(topo: Topology, rt: RoutingTables, tt: TrafficTable,
     reselect = bool(phy_on and phy_spec.reselect)
     living = drift_on or reselect
 
-    # arbitration candidate tables: buffers feeding each switch ...
+    # arbitration candidate sets: buffers feeding each switch ...
     in_bufs: list[list[int]] = [[] for _ in range(S)]
     for b in range(rx0 + n_wi):
         if b_dst[b] < topo.n_switches:
             in_bufs[int(b_dst[b])].append(b)
-    CS = max(_bucket(max((len(x) for x in in_bufs), default=1), 4),
-             fl.get("CS", 0))
-    cands = np.full((S, CS), B, np.int32)
-    for s in range(topo.n_switches):
-        cands[s, :len(in_bufs[s])] = in_bufs[s]
     # ... and buffers able to transmit to each wireless receiver
     senders: list[list[int]] = [[] for _ in range(WMAX)]
     for p in range(Wp):
@@ -1633,15 +1602,10 @@ def pack(topo: Topology, rt: RoutingTables, tt: TrafficTable,
         senders[dst_wi].append(int(topo.wi_switch[src_wi]))
     cr_lists = [sorted({b for s in set(sw) for b in in_bufs[s]})
                 for sw in senders]
-    CR = max(_bucket(max((len(x) for x in cr_lists), default=1), 16),
-             fl.get("CR", 0))
-    candr = np.full((WMAX, CR), B, np.int32)
-    for w in range(n_wi):
-        candr[w, :len(cr_lists[w])] = cr_lists[w]
     wi_sw = np.full(WMAX, S - 1, np.int32)
     wi_sw[:n_wi] = topo.wi_switch
-    # the same candidate sets as [target, buffer] masks; padding rows (the
-    # dummy switch S-1, WIs past n_wi) hold no candidate
+    # as [target, buffer] masks; padding rows (the dummy switch S-1, WIs
+    # past n_wi) hold no candidate
     cand_s = np.zeros((S, B), bool)
     for s in range(topo.n_switches):
         cand_s[s, in_bufs[s]] = True
@@ -1742,7 +1706,6 @@ def pack(topo: Topology, rt: RoutingTables, tt: TrafficTable,
         next_out=jnp.asarray(next_out),
         o_buf=jnp.asarray(o_buf), o_wo=jnp.asarray(o_wo),
         o_is_wl=jnp.asarray(o_is_wl), o_is_ej=jnp.asarray(o_is_ej),
-        cands=jnp.asarray(cands), candr=jnp.asarray(candr),
         cand_w=jnp.asarray(cand_w), cand_r=jnp.asarray(cand_r),
         cand_s=jnp.asarray(cand_s),
         wi_sw=jnp.asarray(wi_sw), rxw=jnp.int32(RXW),
@@ -1801,7 +1764,7 @@ def pack(topo: Topology, rt: RoutingTables, tt: TrafficTable,
         wl_drift_period=jnp.int32(max(1, phy_spec.drift_period)
                                   if phy_on else 1),
     )
-    dims = {"B": B, "S": S, "R": R, "K": K, "CS": CS, "CR": CR,
+    dims = {"B": B, "S": S, "R": R, "K": K,
             "M": M, "P": P, "Y": Y, "BK": BK}
     return PackedSim(ss=ss, B=B, n_cores=topo.n_cores, Lw=Lw,
                      n_inj=n_inj, topo=topo, rt=rt, phy=phy, sim=sim,

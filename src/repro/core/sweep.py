@@ -40,7 +40,7 @@ from repro.core.metrics import Metrics, compute_metrics_batch
 from repro.core.routing import compute_routing
 from repro.core.topology import Topology, build_xcym
 
-HARMONIZED_DIMS = ("B", "S", "R", "K", "CS", "CR", "M", "P", "Y", "BK")
+HARMONIZED_DIMS = ("B", "S", "R", "K", "M", "P", "Y", "BK")
 
 
 @functools.lru_cache(maxsize=64)

@@ -7,8 +7,9 @@ one-assignments in ``simulator.py``, per-pair scatters in
 ``pair_busy`` and the ``wl_*``/``pkts_dropped`` counters) must agree
 bitwise across media and MAC modes.
 
-``simulator.py``'s candidate-table/gather step must produce *bitwise*
-identical dynamics to the original scatter/segment implementation kept in
+``simulator.py``'s step (masked-min arbitration over candidate masks,
+the one form on every backend) must produce *bitwise* identical
+dynamics to the original scatter/segment implementation kept in
 ``simulator_ref.py``.  ``out_wo`` is excluded: it is a static arbitration
 key whose encoding intentionally changed (ejection -> switch id, wireless
 -> receiver id); it never leaves the step.  ``mc_src`` is the reference
@@ -20,12 +21,10 @@ The closed-loop memory state (``rdy``, ``outst``, ``bank_busy`` /
 engines and is compared like everything else — the bank model and reply
 gating are pinned from two independent formulations (ISSUE 3).
 """
-import functools
-
 import numpy as np
 import pytest
 
-from repro.core import arbitrate, simulator, simulator_ref, traffic
+from repro.core import simulator, simulator_ref, traffic
 from repro.core.constants import (DEFAULT_PHY, Fabric, MacMode, PhyParams,
                                   SimParams)
 from repro.core.routing import compute_routing
@@ -35,10 +34,11 @@ from repro.workloads.trace import Trace, mcast, p2p, phase
 SKIP_FIELDS = {"out_wo", "mc_src"}
 
 
-def _compare(topo, rt, tt, phy, sim, phy_spec=None, run=simulator.run):
+def _compare(topo, rt, tt, phy, sim, phy_spec=None):
     so = simulator_ref.run(
         simulator_ref.pack(topo, rt, tt, phy, sim, phy_spec=phy_spec))
-    sn = run(simulator.pack(topo, rt, tt, phy, sim, phy_spec=phy_spec))
+    sn = simulator.run(
+        simulator.pack(topo, rt, tt, phy, sim, phy_spec=phy_spec))
     for f in so._fields:
         if f in SKIP_FIELDS or f not in sn._fields:
             continue
@@ -275,15 +275,13 @@ def test_engines_equivalent_living_uniform():
 LIVING_SEED = 1835504127
 
 
-@pytest.mark.parametrize("form", ["gather", "dense"])
-def test_engines_equivalent_living_reclaimed_rx_vc(form, monkeypatch):
+def test_engines_equivalent_living_reclaimed_rx_vc():
     """A living channel lane (19 dB budget, 4 dB drift, the slowest fixed
     rate, 4 ARQ attempts) where, at cycle 620, an rx buffer has handed
     its VC to the next packet while the old sender still streams an
     attempt into it: the drift moved the link's PER threshold inside an
     earlier attempt, so the receiver filled up early.  The reference
-    delivers those flits sender-side; both arbitration forms must too
-    (dense: forced in, as in tests/test_dense_select.py)."""
+    delivers those flits sender-side; the program must too."""
     from repro.phy import PhySweepSpec
     topo = build_xcym(4, 4, Fabric.WIRELESS)
     rt = compute_routing(topo)
@@ -292,11 +290,5 @@ def test_engines_equivalent_living_reclaimed_rx_vc(form, monkeypatch):
                                 seed=LIVING_SEED)
     spec = PhySweepSpec(link_budget_db=19.0, policy="fixed:-1", max_retx=4,
                         seed=LIVING_SEED, drift_amp_db=4.0, drift_period=8)
-    run = simulator.run
-    if form == "dense":
-        from test_dense_select import _run
-        monkeypatch.setattr(arbitrate, "on_tpu",
-                            lambda dense, gather, *args: dense(*args))
-        run = functools.partial(_run, cycles=sim.cycles)
-    sn = _compare(topo, rt, tt, DEFAULT_PHY, sim, phy_spec=spec, run=run)
+    sn = _compare(topo, rt, tt, DEFAULT_PHY, sim, phy_spec=spec)
     assert int(sn.wl_nacks) > 0
