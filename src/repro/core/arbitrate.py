@@ -19,6 +19,10 @@ work.  Per-slot operands are transposed to ``[V, B]`` first, so the long
 buffer axis is the minor one.  Priority codes are unique, so each target
 has at most one winner (``tests/test_dense_select.py`` pins every helper
 against gather forms of the same searches).
+
+Reads of the step's large per-slot fields at a run-time index (a winner's
+slot, a target buffer, a feeding slot) stay gathers, and ``take_rows``
+makes the fields read at one index a single gather of their stacked rows.
 """
 from __future__ import annotations
 
@@ -55,6 +59,33 @@ def take(table, idx):
         return (hot & table).any(axis=-1)
     return jnp.where(hot, table, jnp.zeros((), table.dtype)).sum(
         axis=-1, dtype=table.dtype)
+
+
+def take_rows(idx, *fields) -> tuple:
+    """``tuple(f[idx] for f in fields)`` with one gather.
+
+    The fields share their leading axis.  Each is cast to int32 (bool,
+    int8 and int16 widen without loss) and its trailing axes flattened;
+    stacked, they make one ``[C, rows]`` table, fetched along its minor
+    axis at ``idx`` at once, and each field's part of the result is cut
+    off the major axis and cast back to its dtype.  The TPU pays for a
+    gather per index it fetches, not per column, so fields read at one
+    index cost one gather, not one each; with the fields on the major
+    axis, cutting them apart moves no data across lanes.
+    """
+    assert all(f.dtype == jnp.bool_ or (
+        jnp.issubdtype(f.dtype, jnp.signedinteger) and f.dtype.itemsize <= 4)
+        for f in fields), [f.dtype for f in fields]
+    rows = fields[0].shape[0]
+    cols = [f.reshape(rows, -1).astype(jnp.int32) for f in fields]
+    got = jnp.take(jnp.concatenate(cols, axis=1).T, idx, axis=1)
+    out, at = [], 0
+    for f, c in zip(fields, cols):
+        n = c.shape[1]
+        out.append(jnp.moveaxis(got[at:at + n], 0, -1)
+                   .reshape(idx.shape + f.shape[1:]).astype(f.dtype))
+        at += n
+    return tuple(out)
 
 
 def member(ss, mcid_c):

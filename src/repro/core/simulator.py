@@ -150,8 +150,16 @@ form on both backends, with no such gather: a search is one masked
 ``min`` over every (buffer, vc) slot, limited to each target's
 contenders by its mask; a slot reads its target's winner, its multicast
 members or its target's free VCs by a one-hot compare and reduce over
-the small table's rows.  The remaining ``[B, V]``-sized reads of
-downstream state and of the winners' fields are gathers.
+the small table's rows.  The remaining ``[B, V]``-sized reads at a
+run-time index (a winner's slot, a target buffer, a feeding slot) stay
+gathers, and the TPU pays for a gather per index it fetches, whatever
+the number of fields.  So the fields read at one index are fetched by
+one gather of the fields stacked as int32 rows (``arbitrate.take_rows``):
+one at the feeding slot ``src_of`` for every delivery read, one at the
+claimed downstream VC ``(out_buf, out_vc)`` for its state and one at the
+target buffer for its depth and link, one at the VC-claim winner, one
+at the routing output, and on the lossy path one at the sender WI's row
+of the per-pair tables and one at the air winners.
 
 Injection moves values between the sources and their injection buffers
 as one block, with no gather and one form on both backends: ``pack``
@@ -489,7 +497,8 @@ def init_state(B: int, N: int, P: int = 1, K: int = 1, Y: int = 1,
 def _route_fields(ss: SimStatic, at_switch: jnp.ndarray, dst: jnp.ndarray):
     """Gather routing decision for packets at `at_switch` going to `dst`."""
     oo = ss.next_out[at_switch, dst]
-    return oo, ss.o_buf[oo], ss.o_wo[oo], ss.o_is_wl[oo], ss.o_is_ej[oo]
+    return (oo,) + arbitrate.take_rows(oo, ss.o_buf, ss.o_wo, ss.o_is_wl,
+                                       ss.o_is_ej)
 
 
 def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
@@ -622,38 +631,39 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         win_mc = need_mc & win_all_mc
         win = win_uni | win_mc
 
-        def g(a):            # winner's field per target buffer -> [B]
-            return a.reshape(-1)[wsrc]
+        # the winner's fields per target buffer ([B]), in one gather
+        (w_ffc, w_src, w_idx, w_dst, w_born, w_ph2, w_mcid, w_mc,
+         w_group_ok) = arbitrate.take_rows(wsrc, *(a.reshape(-1) for a in (
+             first_free_c, st.pkt_src, st.pkt_idx, st.pkt_dst, st.born,
+             st.phase2, st.mc_id, mcf0, win_all_mc)))
 
         # target side: suppress a partial multicast winner (nobody claims
         # that buffer this cycle), and deliver each member copy to its own
         # per-WI destination from the group table
-        w_mc = mcf0.reshape(-1)[wsrc]                            # [B]
-        w_group_ok = win_all_mc.reshape(-1)[wsrc]                # [B]
         has_win_eff = has_win & ((w_mc < 0) | w_group_ok)
         vfree_self = jnp.argmax(free_mask, axis=-1).astype(i32)  # [B]
-        vstar = jnp.where(ss.b_is_rx, vfree_self, g(first_free_c))
+        vstar = jnp.where(ss.b_is_rx, vfree_self, w_ffc)
         claimed = has_win_eff[:, None] & (vstar[:, None] == vcol)  # [B, V]
         mc_dst_w = ss.mc_dst[jnp.clip(w_mc, 0, M - 1), rx_slot]  # [B]
         dst_w = jnp.where(ss.b_is_rx & (w_mc >= 0),
-                          jnp.clip(mc_dst_w, 0, S - 1), g(st.pkt_dst))
+                          jnp.clip(mc_dst_w, 0, S - 1), w_dst)
         d_oo, d_ob, d_owo, d_owl, d_oej = _route_fields(ss, ss.b_dst, dst_w)
 
         def upd(old, val_b):
             return jnp.where(claimed, val_b[:, None], old)
 
-        pkt_src = upd(st.pkt_src, g(st.pkt_src))
-        pkt_idx = upd(st.pkt_idx, g(st.pkt_idx))
+        pkt_src = upd(st.pkt_src, w_src)
+        pkt_idx = upd(st.pkt_idx, w_idx)
         pkt_dst = upd(st.pkt_dst, dst_w)
-        born = upd(st.born, g(st.born))
+        born = upd(st.born, w_born)
         out_o = upd(st.out_o, d_oo.astype(i32))
         out_buf = upd(st.out_buf, d_ob.astype(i32))
         out_wo = upd(st.out_wo, d_owo.astype(i32))
         out_is_wl = upd(st.out_is_wl, d_owl)
         out_is_ej = upd(st.out_is_ej, d_oej)
         out_vc = jnp.where(claimed, -1, st.out_vc)
-        phase2 = upd(st.phase2, g(st.phase2) | ss.b_is_rx)
-        mc_id = upd(st.mc_id, g(st.mc_id))
+        phase2 = upd(st.phase2, w_ph2 | ss.b_is_rx)
+        mc_id = upd(st.mc_id, w_mcid)
         attempt = jnp.where(claimed, 0, st.attempt)
         rcvd = jnp.where(claimed, 0, rcvd)
         sent = jnp.where(claimed, 0, st.sent)
@@ -675,10 +685,12 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         pidx_c = jnp.clip(pkt_idx, 0, Kk - 1)
         way_bv = vcol % ss.b_ej_ways[:, None]                    # [B, V]
         if mem_on:
-            plen_bv = ss.lens[psrc_c, pidx_c]                    # [B, V]
-            op_bv = jnp.where(active, ss.mem_op[psrc_c, pidx_c], 0)
+            plen_bv, op_all, ch_all, rb = arbitrate.take_rows(
+                psrc_c * Kk + pidx_c, *(a.reshape(-1) for a in (
+                    ss.lens, ss.mem_op, ss.mem_ch, ss.req_birth)))  # [B, V]
+            op_bv = jnp.where(active, op_all, 0)
             memrq_bv = (op_bv == 1) | (op_bv == 2)
-            ch_bv = jnp.clip(ss.mem_ch[psrc_c, pidx_c], 0, EJ_WAYS - 1)
+            ch_bv = jnp.clip(ch_all, 0, EJ_WAYS - 1)
             # a request's ejection way IS its pseudo-channel: per-way
             # arbitration then admits one request per (stack, ch)/cycle
             way_bv = jnp.where(memrq_bv & out_is_ej,
@@ -691,19 +703,24 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         inflight = pipe.sum(axis=2)                              # [B, V]
         ob_c = jnp.clip(out_buf, 0, B - 1)
         ovc_c = jnp.clip(out_vc, 0, V - 1)
-        occ_down = rcvd[ob_c, ovc_c] - sent[ob_c, ovc_c]
-        space = ss.b_depth[ob_c] - occ_down - inflight[ob_c, ovc_c]
-        link_free = jnp.take(st.busy_until, ob_c) <= t
+        # the claimed downstream VC's state in one gather, the target
+        # buffer's depth and link in another
+        rcvd_d, sent_d, infl_d = arbitrate.take_rows(
+            ob_c * V + ovc_c, rcvd.reshape(-1), sent.reshape(-1),
+            inflight.reshape(-1))
+        depth_d, busy_d, lat_d, serv_d = arbitrate.take_rows(
+            ob_c, ss.b_depth, st.busy_until, ss.b_lat, ss.b_serv)
+        occ_down = rcvd_d - sent_d
+        space = depth_d - occ_down - infl_d
+        link_free = busy_d <= t
         # multicast sender: backpressure is the MINIMUM over its member
         # copies (located via the src_of inverse map on the rx region) —
         # a broadcast flit flies only when every member can accept it
         is_mc = (mc_id >= 0) & out_is_wl & ~phase2 & active      # [B, V]
         mcid_c = jnp.clip(mc_id, 0, M - 1)
         member = arbitrate.member(ss, mcid_c)                    # [B, V, W]
-        srcof_rx = src_of[rx_ids]                                # [W, V]
-        occ_rx = occ[rx_ids]
-        infl_rx = inflight[rx_ids]
-        depth_rx = ss.b_depth[rx_ids]                            # [W]
+        srcof_rx, occ_rx, infl_rx, depth_rx, busy_rx = arbitrate.take_rows(
+            rx_ids, src_of, occ, inflight, ss.b_depth, st.busy_until)
         cp = srcof_rx[None, None, :, :] \
             == flat2d[:, :, None, None]                          # [B,V,W,V]
         BIGS = jnp.int32(1 << 30)
@@ -713,7 +730,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         cp_space = jnp.where(cp.any(axis=-1), cp_space, 0)       # no copy yet
         space_mc = jnp.where(member, cp_space, BIGS).min(axis=-1)
         space = jnp.where(is_mc, space_mc, space)
-        busy_rx_ok = jnp.take(st.busy_until, rx_ids) <= t        # [W]
+        busy_rx_ok = busy_rx <= t                                # [W]
         lf_mc = jnp.where(member, busy_rx_ok[None, None, :],
                           True).all(axis=-1)
         link_free = jnp.where(is_mc, lf_mc, link_free)
@@ -738,11 +755,19 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             # the packed static ones (refreshed by the update above).
             serv_tab = st.wl_serv_d if living else ss.wl_serv
             perq_tab = st.wl_perq_d if living else ss.wl_perq
+            # the sender WI's rows of the per-pair tables, one gather;
+            # each slot's own pair is then picked by a one-hot compare
             ws_b = jnp.clip(ss.b_wi, 0, WMAX - 1)                # [B]
-            ws_bv = ws_b[:, None]                                # [B, 1]
             wd_bv = jnp.clip(out_wo, 0, WMAX - 1)                # [B, V]
-            serv_wl_bv = serv_tab[ws_bv, wd_bv]                  # [B, V]
-            perq_bv = perq_tab[ws_bv, wd_bv]
+            serv_row, perq_row, pb_row = arbitrate.take_rows(
+                ws_b, serv_tab, perq_tab, st.pair_busy)          # [B, W]
+            wd_hot = wd_bv[..., None] == warr                    # [B, V, W]
+
+            def at_wd(row):
+                return jnp.where(wd_hot, row[:, None, :], 0).sum(axis=-1)
+
+            serv_wl_bv = at_wd(serv_row)                         # [B, V]
+            perq_bv = at_wd(perq_row)
             # broadcast ARQ (ISSUE 6): a multicast attempt is paced and
             # CRC-checked against its WORST member link — group service
             # time and PER threshold are the max over member links.  The
@@ -750,13 +775,13 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             # outcomes are comonotone: "any member fails" is exactly
             # "the worst member fails", i.e. worst-link group
             # retransmission with all-or-nothing delivery to the set.
-            serv_mc = jnp.where(member, serv_tab[ws_b][:, None, :],
+            serv_mc = jnp.where(member, serv_row[:, None, :],
                                 0).max(axis=-1)                  # [B, V]
-            perq_mc = jnp.where(member, perq_tab[ws_b][:, None, :],
+            perq_mc = jnp.where(member, perq_row[:, None, :],
                                 0).max(axis=-1)
             serv_wl_bv = jnp.where(is_mc, serv_mc, serv_wl_bv)
             perq_bv = jnp.where(is_mc, perq_mc, perq_bv)
-            pb_ok = st.pair_busy[ws_bv, wd_bv] <= t
+            pb_ok = at_wd(pb_row) <= t
             wl_ok &= ~out_is_wl | (whole & pb_ok)
             # packet uid is padding-independent (pkt_idx < 2^16 always),
             # so batched and single-point runs draw identical outcomes
@@ -766,7 +791,6 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         elig = active & (occ > 0) & wl_ok & hold_ok \
             & (out_is_ej | ((out_vc >= 0) & (space > 0) & link_free))
         code2 = jnp.where(elig, score * NCp1 + flat2d, BIGC)
-        obf = out_buf.reshape(-1)
         mcf = jnp.where(is_mc, mc_id, -1)
 
         # wired-output winners: one flit per link per cycle
@@ -897,13 +921,17 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             code_yc = win2_ej[:, jnp.clip(ss.stack_sw, 0, S - 1)].T
             valid = code_yc < BIGC                               # [Y, CH]
             slot_yc = jnp.where(valid, code_yc % NCp1, 0)
-            n_w = jnp.clip(psrcf[slot_yc], 0, Nn - 1)
-            k_w = jnp.clip(pidxf[slot_yc], 0, Kk - 1)
-            opw = jnp.where(valid & tailf[slot_yc],
-                            ss.mem_op[n_w, k_w], 0)              # [Y, CH]
+            src_w, idx_w, tail_w = arbitrate.take_rows(
+                slot_yc, psrcf, pidxf, tailf)                    # [Y, CH]
+            n_w = jnp.clip(src_w, 0, Nn - 1)
+            k_w = jnp.clip(idx_w, 0, Kk - 1)
+            op_w, bank_w, row_w, rrow, rslot, len_w = arbitrate.take_rows(
+                n_w * Kk + k_w, *(a.reshape(-1) for a in (
+                    ss.mem_op, ss.mem_bank, ss.mem_row, ss.reply_row,
+                    ss.reply_slot, ss.lens)))
+            opw = jnp.where(valid & tail_w, op_w, 0)
             is_rq = (opw == 1) | (opw == 2)
-            bank_w = jnp.clip(ss.mem_bank[n_w, k_w], 0, BKp - 1)
-            row_w = ss.mem_row[n_w, k_w]
+            bank_w = jnp.clip(bank_w, 0, BKp - 1)
             bb = jnp.take_along_axis(
                 bank_busy, bank_w[:, :, None], axis=2)[:, :, 0]
             br = jnp.take_along_axis(
@@ -917,8 +945,8 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             bank_busy = jnp.where(updm, done[:, :, None], bank_busy)
             bank_row = jnp.where(updm, row_w[:, :, None], bank_row)
             # reply birth: one-assignment min into the paired slot's rdy
-            rrow = jnp.clip(ss.reply_row[n_w, k_w], 0, Nn - 1)
-            rslot = jnp.clip(ss.reply_slot[n_w, k_w], 0, Kk - 1)
+            rrow = jnp.clip(rrow, 0, Nn - 1)
+            rslot = jnp.clip(rslot, 0, Kk - 1)
             rflat = jnp.where(is_rq, rrow * Kk + rslot, -1).reshape(-1)
             m_rdy = jnp.arange(Nn * Kk, dtype=i32)[:, None] == rflat[None]
             val = jnp.where(m_rdy, done.reshape(-1)[None], NOPKT).min(axis=1)
@@ -935,12 +963,10 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             mem_svc_sum = mem_svc_sum + postf * jnp.where(
                 is_rq, svc.astype(f32), 0.0).sum(1)
             data_w = jnp.where(rd_w, ss.lens[rrow, rslot],
-                               jnp.where(wr_w, ss.lens[n_w, k_w], 0))
+                               jnp.where(wr_w, len_w, 0))
             mem_flits = mem_flits + post * data_w.sum(1).astype(i32)
             # (b) reply/ack completion at the requester: AMAT + credit
-            op_all = ss.mem_op[psrc_c, pidx_c]                   # [B, V]
             is_rep = tail_ej & ((op_all == 3) | (op_all == 4))
-            rb = ss.req_birth[psrc_c, pidx_c]
             amat_ok = is_rep & (op_all == 3) & (rb >= ss.warmup)
             amat_sum = amat_sum + post * jnp.where(
                 amat_ok, (t - rb + 1).astype(f32), 0.0).sum()
@@ -950,9 +976,11 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             code_ns = win2_ej[:, jnp.clip(ss.src_switch, 0, S - 1)]
             v_ns = code_ns < BIGC                                # [EJ, N]
             slot_ns = jnp.where(v_ns, code_ns % NCp1, 0)
-            rep_ns = v_ns & is_rep.reshape(-1)[slot_ns]
-            req_ns = ss.req_src[jnp.clip(psrcf[slot_ns], 0, Nn - 1),
-                                jnp.clip(pidxf[slot_ns], 0, Kk - 1)]
+            rep_s, src_s, idx_s = arbitrate.take_rows(
+                slot_ns, is_rep.reshape(-1), psrcf, pidxf)       # [EJ, N]
+            rep_ns = v_ns & rep_s
+            req_ns = ss.req_src[jnp.clip(src_s, 0, Nn - 1),
+                                jnp.clip(idx_s, 0, Kk - 1)]
             Narr = jnp.arange(ss.src_switch.shape[0], dtype=i32)
             dec = (rep_ns & (req_ns == Narr[None, :])).sum(0).astype(i32)
             outst = outst - dec
@@ -977,35 +1005,43 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             ctrl_bv = ss.ctrl_cycles
             lat_wl_bv = ss.lat_wl
             serv_wl_bv = ss.serv_wl
-        lat_t = jnp.where(out_is_wl, lat_wl_bv, ss.b_lat[ob_c]) \
+        lat_t = jnp.where(out_is_wl, lat_wl_bv, lat_d) \
             + jnp.where(first_wl & ~ss.wl_rx_busy, ctrl_bv, 0)
-        serv_t = jnp.where(out_is_wl, serv_wl_bv, ss.b_serv[ob_c]) \
+        serv_t = jnp.where(out_is_wl, serv_wl_bv, serv_d) \
             + jnp.where(first_wl, ctrl_bv, 0)
 
         sv = jnp.clip(src_of, 0, NC - 1)
+        feed_f = (is_mc, out_buf, out_vc, mc_id, fwd, out_is_wl, lat_t,
+                  serv_t, tail)
+        if phy_on:
+            stage("step.phy")
+            # failing attempts occupy the channel/receiver but deliver
+            # nothing; the dropped packet's receiver VC is freed below
+            deliver = fwd & ~(out_is_wl & fail_bv)
+            feed_f += (deliver, drop)
+            stage("step.forward")
+        # the feeding slot's fields, in one gather at sv
+        (f_mc, f_ob, f_ovc, f_mcid, f_fwd, f_wl, f_lat, f_serv, f_tail,
+         *f_phy) = arbitrate.take_rows(sv, *(x.reshape(-1) for x in feed_f))
         # unicast identity: the upstream slot still targets me at my VC.
         # multicast copy identity: my feeder is a multicast-air sender of
         # my own group (one transmission fans out to every member copy).
-        is_mc_f = is_mc.reshape(-1)
-        ident_uni = (src_of >= 0) & ~is_mc_f[sv] \
-            & (obf[sv] == b_ids[:, None]) \
-            & (out_vc.reshape(-1)[sv] == vcol)
-        ident_mc = (src_of >= 0) & is_mc_f[sv] & ss.b_is_rx[:, None] \
-            & (mc_id >= 0) & (mc_id.reshape(-1)[sv] == mc_id)
+        ident_uni = (src_of >= 0) & ~f_mc & (f_ob == b_ids[:, None]) \
+            & (f_ovc == vcol)
+        ident_mc = (src_of >= 0) & f_mc & ss.b_is_rx[:, None] \
+            & (mc_id >= 0) & (f_mcid == mc_id)
         ident = ident_uni | ident_mc
         if phy_on:
             # unicast air flits reach the rx buffers sender-side (below)
             feed = ident_mc | (ident_uni & ~ss.b_is_rx[:, None])
         else:
             feed = ident
-        incoming_any = feed & fwd.reshape(-1)[sv]                # [B, V]
+        incoming_any = feed & f_fwd                              # [B, V]
         busy_until = st.busy_until
         if phy_on:
             stage("step.phy")
-            # failing attempts occupy the channel/receiver but deliver
-            # nothing; the dropped packet's receiver VC is freed below
-            deliver = fwd & ~(out_is_wl & fail_bv)
-            incoming = feed & deliver.reshape(-1)[sv]
+            f_deliver, f_drop = f_phy
+            incoming = feed & f_deliver
             # a unicast air flit lands in the receiver VC its sender
             # names (out_buf, out_vc), whoever holds that VC now: src_of
             # names only its newest feeder (module docstring, Lossy PHY).
@@ -1018,24 +1054,26 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
 
             wa_ok = win2_wl < BIGC                               # [RXW, W]
             wa = jnp.where(wa_ok, win2_wl % NCp1, 0)
+            # the air winners' fields ([RXW, W]), in one gather at wa
+            wa_f = (fwd, out_is_wl, fail_bv, drop, serv_t, is_mc, ovc_c,
+                    lat_t, wd_bv, jnp.broadcast_to(ss.b_wi[:, None], (B, V)))
+            if mem_on:
+                wa_f += (pkt_src, pkt_idx)
+            (a_fwd, a_wl, fail_a, drop_a, serv_a, a_mc, a_ovc, a_lat, a_wd,
+             a_wi, *a_pkt) = arbitrate.take_rows(
+                 wa, *(x.reshape(-1) for x in wa_f))
 
-            def at_wa(x):    # the air winners' field, [RXW, W]
-                return x.reshape(-1)[wa]
-
-            on_air = wa_ok & at_wa(fwd) & at_wa(out_is_wl)
-            fail_a = at_wa(fail_bv)
-            drop_a = at_wa(drop)
-            serv_a = at_wa(serv_t)
-            air = on_air & ~at_wa(is_mc)
+            on_air = wa_ok & a_fwd & a_wl
+            air = on_air & ~a_mc
             air_in = air & ~fail_a
-            air_vc = at_wa(ovc_c)[:, :, None] == varr            # [RXW, W, V]
-            air_d = jnp.clip(at_wa(lat_t) - 1, 0, DMAX - 1)
+            air_vc = a_ovc[:, :, None] == varr                   # [RXW, W, V]
+            air_d = jnp.clip(a_lat - 1, 0, DMAX - 1)
             air_pipe = (air_in[:, :, None, None] & air_vc[:, :, :, None]
                         & (air_d[:, :, None, None] == jnp.arange(DMAX))
                         ).sum(axis=0).astype(pipe.dtype)         # [W, V, D]
             pipe = pipe + at_rx(air_pipe)
             air_n = at_rx(air_in.sum(axis=0).astype(i32))        # [B]
-            rx_dropped = (ident_mc & drop.reshape(-1)[sv]) | at_rx(
+            rx_dropped = (ident_mc & f_drop) | at_rx(
                 ((air & drop_a)[:, :, None] & air_vc).any(axis=0))
             air_ser = air & ss.wl_rx_busy
             busy_until = jnp.where(
@@ -1045,16 +1083,15 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             stage("step.forward")
         else:
             incoming = incoming_any
-        d_in = jnp.clip(lat_t.reshape(-1)[sv] - 1, 0, DMAX - 1)
+        d_in = jnp.clip(f_lat - 1, 0, DMAX - 1)
         pipe = pipe + (incoming[:, :, None]
                        & (jnp.arange(DMAX) == d_in[:, :, None])
                        ).astype(pipe.dtype)
         # crossbar: wireless winners do not serialize the receiver
-        ser_in = incoming_any & (~out_is_wl.reshape(-1)[sv] | ss.wl_rx_busy)
-        serv_in = serv_t.reshape(-1)[sv]
+        ser_in = incoming_any & (~f_wl | ss.wl_rx_busy)
         busy_until = jnp.where(
             ser_in.any(axis=1),
-            t + jnp.where(ser_in, serv_in, 0).sum(axis=1), busy_until)
+            t + jnp.where(ser_in, f_serv, 0).sum(axis=1), busy_until)
         wl_busy_until = jnp.where(
             is_wl_fwd.any(),
             t + (jnp.where(is_wl_fwd, serv_t, 0)).max(), st.wl_busy_until)
@@ -1097,8 +1134,8 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
                     return (mine & x[None]).any(axis=1)
                 return jnp.where(mine, x[None], 0).sum(axis=1)
 
-            txp = by_sender(on_air & (at_wa(wd_bv) == warr)) \
-                & (by_sender(ss.b_wi[wa // V]) == ws_ids)
+            txp = by_sender(on_air & (a_wd == warr)) \
+                & (by_sender(a_wi) == ws_ids)
             failp = txp & by_sender(fail_a)
             pair_busy = jnp.where(txp, t + by_sender(serv_a), st.pair_busy)
             wl_pair_flits = st.wl_pair_flits + post * txp.astype(i32)
@@ -1126,20 +1163,23 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
                 # each one exactly once (gather style; the reference
                 # engine scatters the same updates).
                 d_on = txp & by_sender(drop_a)                   # [W, W]
-                nd = jnp.clip(by_sender(at_wa(pkt_src)), 0, Nn - 1)
-                kd = jnp.clip(by_sender(at_wa(pkt_idx)), 0, Kk - 1)
-                opd = jnp.where(d_on, ss.mem_op[nd, kd], 0)
+                nd = jnp.clip(by_sender(a_pkt[0]), 0, Nn - 1)
+                kd = jnp.clip(by_sender(a_pkt[1]), 0, Kk - 1)
+                op_d, rq_d, rr_d, rs_d = arbitrate.take_rows(
+                    nd * Kk + kd, *(a.reshape(-1) for a in (
+                        ss.mem_op, ss.req_src, ss.reply_row,
+                        ss.reply_slot)))                         # [W, W]
+                opd = jnp.where(d_on, op_d, 0)
                 is_rqd = (opd == 1) | (opd == 2)
                 is_repd = (opd == 3) | (opd == 4)
                 tgt_d = jnp.where(
                     is_rqd, nd,
-                    jnp.where(is_repd,
-                              jnp.clip(ss.req_src[nd, kd], 0, Nn - 1), -1))
+                    jnp.where(is_repd, jnp.clip(rq_d, 0, Nn - 1), -1))
                 Nar = jnp.arange(Nn, dtype=i32)
                 outst = outst - (tgt_d[None] == Nar[:, None, None]) \
                     .sum(axis=(1, 2)).astype(i32)
-                rrd = jnp.clip(ss.reply_row[nd, kd], 0, Nn - 1)
-                rsd = jnp.clip(ss.reply_slot[nd, kd], 0, Kk - 1)
+                rrd = jnp.clip(rr_d, 0, Nn - 1)
+                rsd = jnp.clip(rs_d, 0, Kk - 1)
                 dflat = jnp.where(is_rqd, rrd * Kk + rsd, -1).reshape(-1)
                 dead = dead | (jnp.arange(Nn * Kk, dtype=i32)[:, None]
                                == dflat[None]).any(1).reshape(Nn, Kk)
@@ -1153,7 +1193,7 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
             wl_pair_flits = st.wl_pair_flits
             wl_fail_flits = st.wl_fail_flits
         # the feeding packet's tail has been sent: the link is quiet again
-        src_of = jnp.where(ident & tail.reshape(-1)[sv], -1, src_of)
+        src_of = jnp.where(ident & f_tail, -1, src_of)
 
         # free VCs whose tail left (phy: also ARQ-dropped senders and
         # the receiver VCs their claims held)
@@ -1260,8 +1300,10 @@ def make_step(B: int, mem_on: bool = False, phy_on: bool = False,
         # ---- 4. receiver wake/sleep accounting ([17]) ---------------------
         stage("step.rx_sleep")
         rx_ids = ss.rx0 + jnp.arange(WMAX, dtype=i32)
-        rx_got = jnp.take(arrive.sum(axis=1), jnp.clip(rx_ids, 0, B - 1)) > 0
-        rx_busy = jnp.take(busy_until, jnp.clip(rx_ids, 0, B - 1)) > t
+        rx_arr, rx_bu = arbitrate.take_rows(
+            jnp.clip(rx_ids, 0, B - 1), arrive.sum(axis=1), busy_until)
+        rx_got = rx_arr > 0
+        rx_busy = rx_bu > t
         rx_active = (rx_got | rx_busy) & (jnp.arange(WMAX) < ss.n_wi)
         n_rx_on = rx_active.sum().astype(i32)
         awake = jnp.where(ss.sleepy, n_rx_on, ss.n_wi)

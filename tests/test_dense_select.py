@@ -6,9 +6,10 @@ here is the step's original form: gathers through candidate index tables
 (the masks' rows as buffer ids, padded with ``B``) and a masked ``min``;
 a slot's table row read by index.  Pinned: each helper equals its
 reference on states drawn at random over real packed systems; the
-pack-time masks hold the candidate sets of the topology; and a whole run
+pack-time masks hold the candidate sets of the topology; a whole run
 with every helper replaced by its reference ends in the same state, leaf
-for leaf, as the program's own run.
+for leaf, as the program's own run; and ``take_rows``, the one gather of
+the fields read at one index, equals indexing each field on its own.
 """
 import dataclasses
 import functools
@@ -26,6 +27,7 @@ from test_tpu_compile import _point
 
 V = simulator.V
 SEEDS = (0, 1)
+B_ROWS = 320     # buffers of the 4C4M point: the step's index length
 
 
 @functools.lru_cache(maxsize=None)
@@ -303,6 +305,39 @@ def test_small_table_lookups(kind):
     _same(slot_winner_gather(*args), arbitrate.slot_winner(*args))
     _same(rx_row_gather(win2_wl, r_mine, V),
           arbitrate.rx_row(win2_wl, r_mine, V))
+
+
+def _extremes(dtype, shape, rng):
+    """Values of ``dtype`` that reach both ends of its range and -1."""
+    if dtype == jnp.bool_:
+        return jnp.asarray(rng.random(shape) < 0.5)
+    info = np.iinfo(dtype)
+    x = rng.integers(info.min, info.max, shape, endpoint=True)
+    x.flat[:3] = (info.min, info.max, -1)
+    return jnp.asarray(x.astype(dtype))
+
+
+@pytest.mark.parametrize("idx_shape", [(B_ROWS,), (B_ROWS, V)],
+                         ids=["B", "BV"])
+@pytest.mark.parametrize("dtype", [jnp.bool_, jnp.int8, jnp.int16,
+                                   jnp.int32], ids=lambda d: d.__name__)
+def test_take_rows_equals_per_field_indexing(dtype, idx_shape):
+    """One gather of stacked rows gives each field's own ``f[idx]``: the
+    field under test sits among fields of every other dtype, one with a
+    trailing axis, and the indices reach the first and last rows."""
+    rng = np.random.default_rng(11)
+    rows = 37
+    fields = [_extremes(dtype, (rows,), rng),
+              _extremes(jnp.int32, (rows, V), rng),
+              _extremes(jnp.bool_, (rows,), rng),
+              _extremes(jnp.int8, (rows, 3), rng),
+              _extremes(dtype, (rows, V), rng)]
+    idx = rng.integers(0, rows, idx_shape).astype(np.int32)
+    idx.flat[0], idx.flat[-1] = 0, rows - 1
+    got = jax.jit(arbitrate.take_rows)(jnp.asarray(idx), *fields)
+    assert len(got) == len(fields)
+    for f, g in zip(fields, got):
+        _same(np.asarray(f)[idx], g)
 
 
 @pytest.mark.parametrize("kind", SYSTEMS)

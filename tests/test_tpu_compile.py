@@ -11,7 +11,10 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import functools
+import math
 import os
+import re
 
 import jax
 import pytest
@@ -108,29 +111,45 @@ def test_step_compiles_for_v5e(one_chip, kind):
     assert mem.alias_size_in_bytes > 0, mem
 
 
-def _ideal_hlo(one_chip, driver: str) -> str:
-    """Optimized HLO text of the ideal 4C4M point's driver for a v5e."""
-    p = _point("ideal")
+@functools.lru_cache(maxsize=None)
+def _point_hlo(one_chip, driver: str, kind: str = "ideal") -> str:
+    """Optimized HLO text of a 4C4M point's driver for a v5e."""
+    p = _point(kind)
     topo, rt, tt, _ = sweep._build_point(p)
-    ps = simulator.pack(topo, rt, tt, p.phy, p.sim)
-    st = simulator.init_state(*simulator._state_dims(ps),
-                              R=int(ps.ss.wl_serv_r.shape[0]))
+    ps = simulator.pack(topo, rt, tt, p.phy, p.sim, phy_spec=p.phy_spec)
+    st = simulator.init_state(
+        *simulator._state_dims(ps), mem_on=ps.mem_on, phy_on=ps.phy_on,
+        living=ps.drift_on or ps.reselect, R=int(ps.ss.wl_serv_r.shape[0]))
     lead = (LANES,) if driver == "_run_mapped" else ()
     args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         lead + x.shape, x.dtype, sharding=one_chip), (ps.ss, st))
     return getattr(simulator, driver).lower(
-        *args, ps.B, False, False, simulator.CHUNK_CYCLES, False,
-        False).compile().as_text()
+        *args, ps.B, ps.mem_on, ps.phy_on, simulator.CHUNK_CYCLES,
+        ps.drift_on, ps.reselect).compile().as_text()
+
+
+def _gathers(hlo: str) -> list:
+    """``(indices, step scope)`` of every gather in an HLO text: the
+    indices it fetches (its output's elements over its slice's) and the
+    innermost ``step.*`` scope of its ``op_name`` (``""`` outside one)."""
+    found = re.findall(r"= \w+\[([\d,]*)\][^=]* gather\(.*?slice_sizes="
+                       r"\{([\d,]*)\}.*?op_name=\"([^\"]*)\"", hlo)
+    assert found, "no gather found at all: the pattern no longer matches"
+
+    def size(dims):
+        return math.prod(int(d) for d in dims.split(",") if d)
+
+    return [(size(out) // size(sl),
+             ([p for p in name.split("/") if p.startswith("step.")]
+              or [""])[-1]) for out, sl, name in found]
 
 
 @pytest.mark.parametrize("driver", ["_run_one", "_run_mapped"])
 def test_step_scopes_survive_the_tpu_compile(one_chip, driver):
     """The TPU compiler keeps every ideal step stage and driver scope in
     the op_name metadata a device trace is split by."""
-    import re
-
     from repro.core import spans
-    hlo = _ideal_hlo(one_chip, driver)
+    hlo = _point_hlo(one_chip, driver)
     named = {part for path in re.findall(r'op_name="([^"]*)"', hlo)
              for part in path.split("/") if part in spans.SCOPES}
     assert named == set(spans.SCOPES) - {"step.memory", "step.window",
@@ -140,20 +159,31 @@ def test_step_scopes_survive_the_tpu_compile(one_chip, driver):
 def test_arbitration_has_no_large_gathers_on_the_tpu(one_chip):
     """On the TPU the step finds arbitration winners and reads small
     tables by dense compare-and-reduce (``core/arbitrate``): no gather
-    under ``step.vc_claim`` or ``step.forward`` of the ideal step holds
-    4,096 elements or more.  The TPU fetches a gather's elements one at a
-    time; with the gather forms this compile held 19 such gathers."""
-    import math
-    import re
-
-    hlo = _ideal_hlo(one_chip, "_run_one")
-    gathers = re.findall(
-        r"= \w+\[([\d,]*)\][^=]* gather\(.*?op_name=\"([^\"]*)\"", hlo)
-    assert gathers, "no gather found at all: the pattern no longer matches"
-    large = [(dims, name) for dims, name in gathers
-             if {"step.vc_claim", "step.forward"} & set(name.split("/"))
-             and math.prod(int(d) for d in dims.split(",") if d) >= 4096]
+    under ``step.vc_claim`` or ``step.forward`` of the ideal step fetches
+    4,096 indices or more.  The TPU pays for a gather per index it
+    fetches, whatever the width of the rows; with the gather forms this
+    compile held 19 gathers of 4,096 elements or more."""
+    large = [g for g in _gathers(_point_hlo(one_chip, "_run_one"))
+             if g[1] in ("step.vc_claim", "step.forward") and g[0] >= 4096]
     assert not large, large
+
+
+@pytest.mark.parametrize("kind,most", [("ideal", 3),
+                                       ("phy_drift_reselect", 4)])
+def test_fields_read_at_one_index_share_one_gather_on_the_tpu(
+        one_chip, kind, most):
+    """The step reads the fields it needs at one index with one gather of
+    their stacked rows (``arbitrate.take_rows``): at most ``most`` gathers
+    of 2,048 indices or more under ``step.forward`` and ``step.phy``, and
+    at most 6 gathers under ``step.vc_claim``.  With a gather per field
+    this compile held 16 (ideal) and 21 (lossy) such large gathers, and
+    16 under ``step.vc_claim``."""
+    gathers = _gathers(_point_hlo(one_chip, "_run_one", kind))
+    large = [g for g in gathers
+             if g[1] in ("step.forward", "step.phy") and g[0] >= 2048]
+    claim = [g for g in gathers if g[1] == "step.vc_claim"]
+    assert len(large) <= most, large
+    assert len(claim) <= 6, claim
 
 
 def test_injection_has_no_expanded_gathers_on_the_tpu(one_chip):
@@ -162,9 +192,7 @@ def test_injection_has_no_expanded_gathers_on_the_tpu(one_chip):
     the TPU expands each ``[N] -> [B]`` gather into 64 scalar extracts
     selected into a fusion of 64 or more operands.  With the gathers this
     compile held 13 such fusions and 4,898 ops under ``step.inject``."""
-    import re
-
-    hlo = _ideal_hlo(one_chip, "_run_one")
+    hlo = _point_hlo(one_chip, "_run_one")
     inject = [line for line in hlo.splitlines()
               if "step.inject" in "".join(
                   re.findall(r'op_name="([^"]*)"', line)).split("/")]
